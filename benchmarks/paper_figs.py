@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +40,7 @@ OUT_DIR = record.OUT_DIR
 # communication volume, which is what the paper measures)
 BENCH_N = {"ijcnn1": 8_000, "webspam": 12_000, "epsilon": 4_000}
 EPOCHS = 12
+_CPU_WORKERS = 8
 
 
 def _ds(name):
@@ -48,6 +49,36 @@ def _ds(name):
 
 def _save(name: str, rows: List[Dict]) -> None:
     record.save(name, rows)
+
+
+def _workers() -> int:
+    """K for the multi-worker sections: every chip present on an
+    accelerator, 8 (fake) devices on a CPU host."""
+    if jax.default_backend() == "cpu":
+        return _CPU_WORKERS
+    return len(jax.devices())
+
+
+def _cpu_child(section: str, prefix: str) -> Optional[List[str]]:
+    """On a CPU host with fewer than 8 devices, run ``section`` in a child
+    that fakes 8 CPU devices and return its ``prefix`` lines; ``None``
+    means run in this process. Only the CPU is ever faked: on an
+    accelerator the section runs here, over the chips present."""
+    if (jax.default_backend() != "cpu"
+            or len(jax.devices()) >= _CPU_WORKERS):
+        return None
+    import subprocess
+    import sys
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={_CPU_WORKERS}"
+    env["JAX_PLATFORMS"] = "cpu"   # the flag only fakes CPU devices
+    env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    out = subprocess.run(
+        [sys.executable, "-m", "benchmarks.paper_figs", section],
+        env=env, capture_output=True, text=True, timeout=1800)
+    if out.returncode != 0:
+        return [f"{prefix},ERROR,,{out.stderr[-200:]}"]
+    return [l for l in out.stdout.splitlines() if l.startswith(prefix)]
 
 
 def fig1_3() -> List[str]:
@@ -120,32 +151,20 @@ def fig10_15() -> List[str]:
     """Comm/compute breakdown vs MSF × parallelism (Figs 10–15).
 
     Paper methodology: instrument around the sync collective. We jit the
-    per-block compute and the pmean sync separately (dms_timed_steps) on a
-    real multi-device host mesh and time each. Run in a subprocess with 8
-    host devices if this process has only 1.
+    per-block compute and the pmean sync separately (dms_timed_steps) over
+    K workers (:func:`_workers`) and time each.
     """
-    n_dev = len(jax.devices())
-    if n_dev < 8:
-        import subprocess
-        import sys
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        env["JAX_PLATFORMS"] = "cpu"   # the flag only fakes CPU devices
-        env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.paper_figs", "fig10_15"],
-            env=env, capture_output=True, text=True, timeout=1800)
-        if out.returncode != 0:
-            return [f"fig10_15,ERROR,,{out.stderr[-200:]}"]
-        return [l for l in out.stdout.splitlines() if l.startswith("fig10_15")]
+    child = _cpu_child("fig10_15", "fig10_15")
+    if child is not None:
+        return child
 
     from repro.launch.mesh import make_test_mesh
-    mesh = make_test_mesh((8,), ("data",))
+    k = _workers()
+    mesh = make_test_mesh((k,), ("data",))
     lines = []
     rows = []
     for dataset in ("ijcnn1", "webspam", "epsilon"):
         ds = _ds(dataset)
-        k = 8
         n = (ds.n_train // k) * k
         xs = jnp.asarray(ds.x_train[:n].reshape(k, n // k, -1))
         ys = jnp.asarray(ds.y_train[:n].reshape(k, n // k))
@@ -225,31 +244,16 @@ def overlap_sweep() -> List[str]:
     The overlap engine's claim (ISSUE 1): delayed/chunked step time ≤
     blocking at every H. Times a jitted scan of dms_block_stepper blocks on
     the synthetic Epsilon stand-in (d=2000 — the sync-bytes-heavy dataset),
-    8 workers, min over repeats. Run in a subprocess with 8 host devices if
-    this process has only 1.
+    K workers (:func:`_workers`), min over repeats.
     """
-    n_dev = len(jax.devices())
-    if n_dev < 8:
-        import subprocess
-        import sys
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        # pin the child to CPU: the flag only fakes CPU devices, so a child
-        # on a 1-7 GPU host would still see <8 devices and recurse
-        env["JAX_PLATFORMS"] = "cpu"
-        env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.paper_figs", "overlap_sweep"],
-            env=env, capture_output=True, text=True, timeout=1800)
-        if out.returncode != 0:
-            return [f"overlap_sweep,ERROR,,{out.stderr[-200:]}"]
-        return [l for l in out.stdout.splitlines()
-                if l.startswith("overlap_sweep")]
+    child = _cpu_child("overlap_sweep", "overlap_sweep")
+    if child is not None:
+        return child
 
     from repro.launch.mesh import make_test_mesh
     from repro.core import svm as svm_mod
-    mesh = make_test_mesh((8,), ("data",))
-    k = 8
+    k = _workers()
+    mesh = make_test_mesh((k,), ("data",))
     chunks = 4        # shard count for overlap="chunked" (measured + model)
     rng = np.random.default_rng(0)
     # (label, x (K, n_local, d), y): epsilon is the paper's byte-heavy
@@ -361,9 +365,8 @@ def gossip_sweep() -> List[str]:
     is the binding constraint — the regime where the guardrail matters.
 
     Section 3 (``sync_us`` rows): measured per-sync wall time of the
-    blocking exchange (dms_timed_steps) on an 8-worker host mesh — the
-    gossip exchange does not pay the global barrier. Run in a subprocess
-    with 8 host devices if this process has only 1.
+    blocking exchange (dms_timed_steps) over K workers (:func:`_workers`)
+    — the gossip exchange does not pay the global barrier.
     """
     from repro.config import SyncConfig
     from repro.core import costmodel
@@ -434,37 +437,20 @@ def gossip_sweep() -> List[str]:
             lines.append(f"gossip_sweep,acc,{dataset} topo={mode} H={h},"
                          f"{acc:.4f} (Δ@H={acc - acc_ref:+.4f})")
 
-    # --- 3) measured per-sync time on a host mesh ----------------------
-    n_dev = len(jax.devices())
-    if n_dev < 8:
-        import subprocess
-        import sys
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        env["JAX_PLATFORMS"] = "cpu"   # the flag only fakes CPU devices
-        env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.paper_figs",
-             "gossip_sweep_timing"],
-            env=env, capture_output=True, text=True, timeout=1800)
-        if out.returncode != 0:
-            lines.append(f"gossip_sweep,ERROR,,{out.stderr[-200:]}")
-        else:
-            lines += [l for l in out.stdout.splitlines()
-                      if l.startswith("gossip_sweep")]
-        _save("gossip_sweep", rows)
-        return lines
-
-    lines += gossip_sweep_timing()
+    # --- 3) measured per-sync time over K workers ----------------------
+    child = _cpu_child("gossip_sweep_timing", "gossip_sweep")
+    lines += gossip_sweep_timing() if child is None else child
     _save("gossip_sweep", rows)
     return lines
 
 
 def gossip_sweep_timing() -> List[str]:
-    """Measured blocking-sync wall time per topology (8 host workers)."""
+    """Measured blocking-sync wall time per topology over K workers."""
     from repro.launch.mesh import make_test_mesh
-    mesh = make_test_mesh((8,), ("data",))
-    k, d = 8, 65_536      # wide model: sync bytes dominate barrier latency
+    k, d = _workers(), 65_536   # wide model: sync bytes dominate latency
+    if k < 2:
+        return ["gossip_sweep,sync_us,SKIP,needs 2+ workers"]
+    mesh = make_test_mesh((k,), ("data",))
     rng = np.random.default_rng(0)
     w_locals = jnp.asarray(rng.normal(size=(k, d)), jnp.float32)
     cnt = jnp.zeros((), jnp.int32)
@@ -473,6 +459,8 @@ def gossip_sweep_timing() -> List[str]:
         for topo, gossip_async in (("all", False), ("ring", False),
                                    ("ring", True), ("pairwise", False),
                                    ("pairwise", True)):
+            if topo == "pairwise" and k % 2:
+                continue          # pairwise needs an even worker count
             _, sync = svm.dms_timed_steps(mesh, "data", block_size=8,
                                           topology=topo,
                                           gossip_async=gossip_async)
@@ -508,8 +496,9 @@ def hinge_kernel() -> List[str]:
     suite fast and the row is labeled ``interpret``.
     """
     from repro.core.svm import block_grad
+    from repro.kernels import default_interpret
     from repro.kernels.hinge import ops as hinge_ops
-    interp = hinge_ops.default_interpret()
+    interp = default_interpret()
     n, d = (256, 128) if interp else (4096, 2048)
     reps = 3 if interp else 20
     rng = np.random.default_rng(0)

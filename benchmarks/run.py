@@ -51,6 +51,8 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default="experiments/bench",
                     help="output directory for --json bundles")
     args = ap.parse_args(argv)
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
 
     reg = registry()
     if args.list:

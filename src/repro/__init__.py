@@ -1,6 +1,1 @@
-"""repro — parallel-SGD SVM / MSF training framework (paper reproduction).
-
-Importing the package installs :mod:`repro.compat`, which backfills
-new-style JAX API names on older jaxlib installs.
-"""
-from repro import compat as _compat  # noqa: F401
+"""repro — parallel-SGD SVM / MSF training framework (paper reproduction)."""

@@ -253,7 +253,7 @@ class DataConfig:
 
 @dataclass(frozen=True)
 class CheckpointConfig:
-    directory: str = "/tmp/repro_ckpt"
+    directory: str = "checkpoints"   # relative to the working directory
     interval_steps: int = 100
     keep_last: int = 3
     async_write: bool = False

@@ -33,7 +33,6 @@ def build_parser(description: str) -> argparse.ArgumentParser:
     p.add_argument("--arch", default="smollm-360m", help="architecture id")
     p.add_argument("--shape", default="train_4k",
                    help="input shape cell: train_4k|prefill_32k|decode_32k|long_500k|smoke")
-    p.add_argument("--multi-pod", action="store_true", help="use the 2x16x16 mesh")
     p.add_argument("--set", dest="overrides", action="append", default=[],
                    metavar="KEY=VALUE", help="dotted config override")
     return p

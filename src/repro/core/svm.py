@@ -50,6 +50,12 @@ Pallas kernel (:mod:`repro.kernels.hinge`).
 * ``"pairwise"`` — rotating disjoint odd–even pairs average with weight ½
   (round parity alternates the pairing); requires an even worker count.
 
+Precision: a margin's sign picks the subgradient branch, so every margin
+and the block-gradient sum are computed at ``Precision.HIGHEST``. A TPU's
+default f32 matmul rounds its operands to bf16; that flips margins near
+zero, and one flipped point sends the whole SGD trajectory elsewhere
+(DMS would no longer track SRDMS). On the CPU the setting changes nothing.
+
 Gossip workers only reach consensus geometrically (factor λ₂ per round —
 :func:`repro.core.costmodel.gossip_lambda2`); the mixing matrix is doubly
 stochastic, so the worker mean is invariant and the final flush
@@ -65,8 +71,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 # ---------------------------------------------------------------------------
 # primitives
@@ -75,12 +82,12 @@ from jax.sharding import PartitionSpec as P
 def hinge_objective(w: jax.Array, x: jax.Array, y: jax.Array,
                     c: float = 1.0) -> jax.Array:
     """Paper eq. (2): ½‖w‖² + C·Σ hinge."""
-    margins = 1.0 - y * (x @ w)
+    margins = 1.0 - y * jnp.matmul(x, w, precision=_HIGHEST)
     return 0.5 * jnp.dot(w, w) + c * jnp.sum(jnp.maximum(0.0, margins))
 
 
 def accuracy(w: jax.Array, x: jax.Array, y: jax.Array) -> jax.Array:
-    pred = jnp.where(x @ w >= 0, 1.0, -1.0)
+    pred = jnp.where(jnp.matmul(x, w, precision=_HIGHEST) >= 0, 1.0, -1.0)
     return jnp.mean(pred == y)
 
 
@@ -100,14 +107,14 @@ def block_grad(w: jax.Array, xb: jax.Array, yb: jax.Array, c: float,
     if impl == "pallas":
         from repro.kernels.hinge import ops as hinge_ops
         return hinge_ops.hinge_block_grad(w, xb, yb, c)
-    margins = 1.0 - yb * (xb @ w)
+    margins = 1.0 - yb * jnp.matmul(xb, w, precision=_HIGHEST)
     viol = (margins > 0).astype(w.dtype)
-    return w - c * ((viol * yb) @ xb) / xb.shape[0]
+    return w - c * jnp.matmul(viol * yb, xb, precision=_HIGHEST) / xb.shape[0]
 
 
 def _point_update(w, x, y, alpha, c):
     """Algorithm 1 inner step (single point)."""
-    margin = 1.0 - y * jnp.dot(x, w)
+    margin = 1.0 - y * jnp.dot(x, w, precision=_HIGHEST)
     grad = jnp.where(margin > 0, w - c * y * x, w)
     return w - alpha * grad
 
@@ -220,10 +227,11 @@ def _dms_vmap(w0, xs, ys, *, epochs: int, block_size: int, c: float,
 
         def mix(w, rnd):
             """w (K, cols) ← M_rnd w; rnd selects the pairwise parity."""
+            mm = functools.partial(jnp.matmul, precision=_HIGHEST)
             if len(mats) == 1:
-                return mats[0] @ w
-            return jax.lax.cond(rnd % 2 == 0, lambda v: mats[0] @ v,
-                                lambda v: mats[1] @ v, w)
+                return mm(mats[0], w)
+            return jax.lax.cond(rnd % 2 == 0, lambda v: mm(mats[0], v),
+                                lambda v: mm(mats[1], v), w)
 
         dp = _padded_width(d, chunks) if overlap == "chunked" else d
         seg = dp // chunks
@@ -472,17 +480,24 @@ def _carry_flush(carry, axis: str, *, overlap: str, d: int,
     return jax.lax.pmean(carry["w"], axis)[:d]
 
 
-def _dms_shard_map(w0, xs, ys, *, epochs: int, block_size: int, c: float,
-                   grad_impl: str, mesh, axis: str = "data",
-                   overlap: str = "none", chunks: int = 4,
-                   topology: str = "all", gossip_async: bool = False):
-    """Real collectives: workers = mesh axis shards; sync = lax.pmean
-    (``topology="all"``) or lax.ppermute neighbor mixing (gossip)."""
-    k = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
-    assert xs.shape[0] == k, (xs.shape, k)
-    d = w0.shape[0]
+@functools.lru_cache(maxsize=64)
+def dms_shard_map_program(mesh, axis: str = "data", *, epochs: int,
+                          block_size: int, c: float = 1.0,
+                          grad_impl: str = "jnp", overlap: str = "none",
+                          chunks: int = 4, topology: str = "all",
+                          gossip_async: bool = False):
+    """The jitted program ``dms(backend="shard_map")`` runs:
+    ``fn(w0, xs, ys) → w`` with ``xs (K, n_local, d)`` / ``ys (K, n_local)``
+    sharded over ``axis`` (K = that axis' size). Real collectives: workers
+    = mesh axis shards; sync = lax.pmean (``topology="all"``) or
+    lax.ppermute neighbor mixing (gossip).
+
+    Cached per configuration, so lowering it (``fn.lower(...).compile()``,
+    to read the HLO or to compile for a described topology) and a
+    ``dms`` call with the same arguments share one compilation."""
 
     def worker(w, x_local, y_local):
+        d = w.shape[0]
         # x_local arrives as (1, n_local, d) — this worker's shard
         x_local, y_local = x_local[0], y_local[0]
         n_local, _ = x_local.shape
@@ -512,7 +527,7 @@ def _dms_shard_map(w0, xs, ys, *, epochs: int, block_size: int, c: float,
     fn = jax.shard_map(worker, mesh=mesh,
                        in_specs=(P(), P(axis), P(axis)), out_specs=P(),
                        axis_names={axis}, check_vma=False)
-    return jax.jit(fn)(w0, xs, ys)
+    return jax.jit(fn)
 
 
 def dms(w0: jax.Array, x: np.ndarray, y: np.ndarray, *, workers: int,
@@ -533,18 +548,24 @@ def dms(w0: jax.Array, x: np.ndarray, y: np.ndarray, *, workers: int,
                          f"overlap='none'; got topology={topology!r}, "
                          f"overlap={overlap!r}")
     xs, ys = _shard_data(np.asarray(x), np.asarray(y), workers)
-    xs, ys = jnp.asarray(xs), jnp.asarray(ys)
     if backend == "vmap":
-        return _dms_vmap(w0, xs, ys, epochs=epochs, block_size=block_size,
-                         c=c, grad_impl=grad_impl, overlap=overlap,
-                         chunks=chunks, topology=topology,
+        return _dms_vmap(w0, jnp.asarray(xs), jnp.asarray(ys), epochs=epochs,
+                         block_size=block_size, c=c, grad_impl=grad_impl,
+                         overlap=overlap, chunks=chunks, topology=topology,
                          gossip_async=gossip_async)
     if backend == "shard_map":
         assert mesh is not None
-        return _dms_shard_map(w0, xs, ys, epochs=epochs, block_size=block_size,
-                              c=c, grad_impl=grad_impl, mesh=mesh, axis=axis,
-                              overlap=overlap, chunks=chunks,
-                              topology=topology, gossip_async=gossip_async)
+        k = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
+        if workers != k:
+            raise ValueError(f"workers={workers} but mesh axis {axis!r} "
+                             f"has {k} devices")
+        # each worker's rows go straight to its own device
+        xs, ys = jax.device_put((xs, ys), NamedSharding(mesh, P(axis)))
+        fn = dms_shard_map_program(
+            mesh, axis, epochs=epochs, block_size=block_size, c=c,
+            grad_impl=grad_impl, overlap=overlap, chunks=chunks,
+            topology=topology, gossip_async=gossip_async)
+        return fn(w0, xs, ys)
     raise ValueError(backend)
 
 
@@ -787,28 +808,43 @@ def dms_block_ladder(mesh, axis: str, *, d: int, workers: int, block_sizes,
 
     One :func:`dms_block_stepper` is traced once (its carry layout is
     block-size independent) and AOT-compiled for every ``bs`` in
-    ``block_sizes``: ``{bs: compiled}`` where ``compiled(carry, xblk,
-    yblk, alpha)`` expects ``xblk (K, bs, d)`` / ``yblk (K, bs)`` and can
-    never retrace or recompile (a shape mismatch raises). A mid-run MSF
-    move is :func:`dms_ladder_switch` on the carry + picking another
-    rung + re-blocking the data stream.
+    ``block_sizes``: ``{bs: rung}`` where ``rung(carry, xblk, yblk,
+    alpha)`` expects ``xblk (K, bs, d)`` / ``yblk (K, bs)`` and can never
+    retrace or recompile (a shape mismatch raises). Each rung is lowered
+    with explicit shardings (worker-dim leaves over ``axis``, ``cnt`` and
+    ``alpha`` replicated) and places its arguments there before the call,
+    so a carry from :func:`dms_stepper_init` or :func:`dms_ladder_switch`
+    is accepted wherever it lives. A mid-run MSF move is
+    :func:`dms_ladder_switch` on the carry + picking another rung +
+    re-blocking the data stream.
     """
     step = dms_block_stepper(mesh, axis, d=d, c=c, grad_impl=grad_impl,
                              overlap=overlap, chunks=chunks,
                              topology=topology, gossip_async=gossip_async)
-    jitted = jax.jit(step)
     carry = dms_stepper_init(jnp.zeros((d,), dtype), workers,
                              overlap=overlap, chunks=chunks,
                              topology=topology, gossip_async=gossip_async)
+    sharded, replicated = (NamedSharding(mesh, P(axis)),
+                           NamedSharding(mesh, P()))
+    carry_sh = {k: (replicated if k == "cnt" else sharded) for k in carry}
+    jitted = jax.jit(step, in_shardings=(carry_sh, sharded, sharded,
+                                         replicated),
+                     out_shardings=carry_sh)
     carry_avals = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), carry)
     alpha_aval = jax.ShapeDtypeStruct((), dtype)
+
+    def placed(compiled):
+        def rung(*args):
+            return compiled(*jax.device_put(args, compiled.input_shardings[0]))
+        return rung
+
     out = {}
     for bs in sorted(set(int(b) for b in block_sizes)):
         x_aval = jax.ShapeDtypeStruct((workers, bs, d), dtype)
         y_aval = jax.ShapeDtypeStruct((workers, bs), dtype)
-        out[bs] = jitted.lower(carry_avals, x_aval, y_aval,
-                               alpha_aval).compile()
+        out[bs] = placed(jitted.lower(carry_avals, x_aval, y_aval,
+                                      alpha_aval).compile())
     return out
 
 

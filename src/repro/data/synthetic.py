@@ -48,6 +48,9 @@ PAPER_DATASETS: Dict[str, Tuple[int, int, float]] = {
 }
 
 
+_DRAW_CHUNK = 1 << 24          # values per random draw (128 MiB of float64)
+
+
 def make_svm_dataset(name: str, seed: int = 0, train_fraction: float = 0.8,
                      scale: float = 1.0, label_noise: float = 0.05,
                      n_override: Optional[int] = None) -> SVMDataset:
@@ -69,13 +72,22 @@ def make_svm_dataset(name: str, seed: int = 0, train_fraction: float = 0.8,
     w_true /= np.linalg.norm(w_true)
 
     density = max(1e-4, 1.0 - sparsity_pct / 100.0)
-    x = rng.normal(scale=scale, size=(n, d)).astype(np.float32)
+    # drawn in row chunks: the generator's stream is sequential, so the
+    # values match one full-size draw, but the float64 intermediates stay
+    # at chunk size (Table I epsilon is 3.2 GB in float32 alone)
+    rows = max(1, _DRAW_CHUNK // d)
+    x = np.empty((n, d), np.float32)
+    for lo in range(0, n, rows):
+        x[lo:lo + rows] = rng.normal(scale=scale, size=(min(rows, n - lo), d))
     if density < 1.0:
-        mask = rng.random(size=(n, d)) < density
+        mask = np.empty((n, d), bool)
+        for lo in range(0, n, rows):
+            mask[lo:lo + rows] = rng.random(size=(min(rows, n - lo), d)) < density
         # keep at least one nonzero per row so no sample is empty
         empty = ~mask.any(axis=1)
         mask[empty, rng.integers(0, d, size=int(empty.sum()))] = True
-        x = x * mask
+        x *= mask
+        del mask
 
     margin = x @ w_true
     y = np.where(margin >= 0, 1.0, -1.0).astype(np.float32)

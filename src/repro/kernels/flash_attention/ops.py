@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import auto_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_padded
 
 _LANE = 128
@@ -23,12 +24,13 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+@auto_interpret
 @functools.partial(jax.jit, static_argnames=(
     "causal", "prefix_len", "block_q", "block_k", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, prefix_len: int = 0,
                     block_q: int = 0, block_k: int = 0,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: (B, S, H, dh) · k/v: (B, S, KV, dh) → (B, S, H, dh)."""
     b, sq, h, dh = q.shape
     sk, kvh = k.shape[1], k.shape[2]

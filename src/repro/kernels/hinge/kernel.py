@@ -35,11 +35,14 @@ def _hinge_kernel(c_over_n, w_ref, x_ref, y_ref, o_ref):
     w = w_ref[...]                       # (1, d)
     x = x_ref[...]                       # (bn, d)
     y = y_ref[...]                       # (1, bn)
+    # f32 passes: a margin's sign picks the subgradient branch
+    hi = jax.lax.Precision.HIGHEST
     margins = 1.0 - y * jax.lax.dot_general(
-        w, x, (((1,), (1,)), ((), ())))  # (1, bn) = w·xᵀ
+        w, x, (((1,), (1,)), ((), ())), precision=hi)  # (1, bn) = w·xᵀ
     viol = jnp.where(margins > 0, y, 0.0)          # yᵢ where violated else 0
     # (1, bn) @ (bn, d) → (1, d) masked accumulation
-    o_ref[...] += jax.lax.dot_general(viol, x, (((1,), (0,)), ((), ())))
+    o_ref[...] += jax.lax.dot_general(viol, x, (((1,), (0,)), ((), ())),
+                                      precision=hi)
 
     @pl.when(i == n_blocks - 1)
     def _finish():

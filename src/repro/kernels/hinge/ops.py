@@ -5,45 +5,43 @@ y = 0 so their hinge contribution vanishes (y multiplies every term);
 padded feature columns are zero in both X and w so they contribute nothing
 to margins and stay zero in the gradient.
 
-``interpret`` defaults to *auto*: compiled Pallas on TPU/GPU backends, the
-interpreter only on CPU (where Pallas has no compiled lowering). The old
-default of ``interpret=True`` everywhere meant ``grad_impl="pallas"`` ran
-the interpreter even on accelerators — the hot path never compiled.
+``interpret`` defaults to the package rule
+(:func:`repro.kernels.default_interpret`): compiled Pallas on TPU/GPU
+backends, the interpreter only on CPU.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import auto_interpret
 from repro.kernels.hinge.kernel import hinge_block_grad_padded
 
 _LANE = 128
+# X row-block budget. The f32 (HIGHEST) dots keep several copies of the
+# block in VMEM and the pipeline double-buffers it: a 4 MiB block at
+# d=2000 needs 16.9 MiB of scoped VMEM on a v5e, over its 16 MiB limit.
+_X_BLOCK_BYTES = 2 << 20
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def default_interpret() -> bool:
-    """Interpret only where Pallas cannot compile (CPU backends)."""
-    return jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm")
-
-
+@auto_interpret
 @functools.partial(jax.jit, static_argnames=("c", "block_n", "interpret"))
 def hinge_block_grad(w: jax.Array, x: jax.Array, y: jax.Array, c: float = 1.0,
-                     *, block_n: int = 0,
-                     interpret: Optional[bool] = None) -> jax.Array:
+                     *, block_n: int = 0, interpret: bool) -> jax.Array:
     """Drop-in for :func:`repro.kernels.hinge.ref.hinge_block_grad`."""
-    if interpret is None:
-        interpret = default_interpret()
     n, d = x.shape
     dp = _round_up(d, _LANE)
     if block_n <= 0:
-        # VMEM-guided default: ≤4 MiB X block, sublane (8) aligned
-        block_n = max(8, min(512, _round_up(n, 8)))
+        # one sublane-aligned block if the rows fit, else a lane-aligned
+        # one (y's row-block is its last dim) within the VMEM budget
+        fit = _X_BLOCK_BYTES // (dp * x.dtype.itemsize) // _LANE * _LANE
+        block_n = min(max(_LANE, fit), 512, _round_up(n, 8))
     npad = _round_up(n, block_n)
 
     xp = jnp.zeros((npad, dp), x.dtype).at[:n, :d].set(x)

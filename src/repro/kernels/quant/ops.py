@@ -7,6 +7,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import auto_interpret
 from repro.kernels.quant.kernel import dequantize_padded, quantize_padded
 
 _LANE = 128
@@ -20,8 +21,9 @@ def _to_tiles(flat: jax.Array) -> Tuple[jax.Array, int]:
     return padded.reshape(m8, _LANE), n
 
 
+@auto_interpret
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def quantize(x: jax.Array, *, interpret: bool = True
+def quantize(x: jax.Array, *, interpret: bool
              ) -> Tuple[jax.Array, jax.Array]:
     """Any-shape fp tensor → (q int8 same shape, scale f32 scalar)."""
     x32 = x.astype(jnp.float32)
@@ -39,9 +41,10 @@ def quantize(x: jax.Array, *, interpret: bool = True
     return q.reshape(-1)[:n].reshape(x.shape), scale
 
 
+@auto_interpret
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def dequantize(q: jax.Array, scale: jax.Array, *,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool) -> jax.Array:
     tiles, n = _to_tiles(q.reshape(-1))
     block_m = min(tiles.shape[0], 512)
     m = tiles.shape[0]

@@ -12,6 +12,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import auto_interpret
 from repro.kernels.ssd.kernel import ssd_scan_padded
 
 
@@ -19,10 +20,11 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+@auto_interpret
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
              cm: jax.Array, *, chunk: int = 128,
-             interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+             interpret: bool) -> Tuple[jax.Array, jax.Array]:
     """Drop-in for :func:`repro.kernels.ssd.ref.ssd_scan` (zero init state)."""
     b, l, h, p = x.shape
     lp = _round_up(l, chunk)

@@ -91,6 +91,8 @@ class ServeEngine:
 def main() -> None:
     p = build_parser("batched serving driver")
     p.add_argument("--smoke", action="store_true")
+    p.add_argument("--multi-pod", action="store_true",
+                   help="use the 2x16x16 mesh")
     p.add_argument("--requests", type=int, default=4)
     p.add_argument("--prompt-len", type=int, default=16)
     p.add_argument("--gen-tokens", type=int, default=8)

@@ -2,15 +2,18 @@
 
 Wires every subsystem: arch config → mesh → sharding rules → model → MSF
 sync engine → optimizer → data pipeline → checkpoint manager →
-fault-tolerant step runner. Runs at any scale the process' devices allow —
-the CPU smoke path (``--arch smollm-360m --smoke``) and a real pod run use
-the same code.
+fault-tolerant step runner. The mesh spans the devices the process has
+(``data`` = device count, ``model`` = 1): the CPU smoke path
+(``--arch smollm-360m --smoke``) and a full-width run on one chip or a
+4-chip host use the same code. Local SGD over the chips is
+``--set mesh.replica_axis=data --set sync.strategy=periodic``.
 
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --smoke \
         --set steps=20 --set sync.strategy=periodic --set sync.period=4
 """
 from __future__ import annotations
 
+import functools
 import json
 import time
 
@@ -23,8 +26,8 @@ from repro.config.cli import apply_overrides, build_parser
 from repro.core import local_sgd as LS
 from repro.core import sync as SY
 from repro.data.pipeline import DataPipeline
-from repro.launch.mesh import (make_production_mesh, make_test_mesh,
-                               production_mesh_config, test_mesh_config)
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import make_test_mesh, test_mesh_config
 from repro.models.registry import build_model
 from repro.runtime import StepRunner
 from repro.sharding import rules_for
@@ -121,13 +124,17 @@ def build_trainer(cfg: TrainConfig, mesh):
         counter = CompileCounter().install()
 
     with jax.set_mesh(mesh):
-        state = LS.init_state(model, cfg, jax.random.key(cfg.seed),
-                              replicas=replicas)
-        step = LS.make_train_step(model, cfg, mesh, rules)
+        # built in place: each replica's copy is made on its own devices
+        # (K full replicas would not fit on the first one)
+        init = functools.partial(LS.init_state, model, cfg,
+                                 replicas=replicas)
+        key = jax.random.key(cfg.seed)
         axes = LS.build_state_axes(model, cfg, replicated=use_replicas)
         shardings = LS.state_shardings(
-            axes, rules, jax.tree.map(lambda x: x.shape, state))
-        state = jax.tree.map(jax.device_put, state, shardings)
+            axes, rules, jax.tree.map(lambda x: x.shape,
+                                      jax.eval_shape(init, key)))
+        state = jax.jit(init, out_shardings=shardings)(key)
+        step = LS.make_train_step(model, cfg, mesh, rules)
         jitted = jax.jit(step, in_shardings=(shardings, None),
                          out_shardings=(shardings, None),
                          donate_argnums=(0,))
@@ -157,28 +164,32 @@ def build_trainer(cfg: TrainConfig, mesh):
     return jitted, state, make_pipeline, model, telemetry, ladder
 
 
-def main() -> None:
+def train(argv=None):
+    """Parse ``argv`` as the CLI does, train, and flush the replicas.
+
+    Returns ``(summary, state)``: the JSON summary ``main`` prints and the
+    final state after :func:`repro.core.local_sgd.finalize_state`.
+    """
     p = build_parser("end-to-end trainer")
     p.add_argument("--smoke", action="store_true",
                    help="reduced config on local devices")
     p.add_argument("--steps", type=int, default=20)
-    args = p.parse_args()
+    args = p.parse_args(argv)
+    use_compile_cache()
 
     model_cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
-    if args.smoke:
-        n_dev = len(jax.devices())
-        mesh = make_test_mesh((n_dev, 1))
-        mesh_cfg = test_mesh_config((n_dev, 1))
-    else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
-        mesh_cfg = production_mesh_config(multi_pod=args.multi_pod)
+    n_dev = len(jax.devices())
+    mesh = make_test_mesh((n_dev, 1))
+    mesh_cfg = test_mesh_config((n_dev, 1))
 
     from repro.config.base import DataConfig
     cfg = TrainConfig(model=model_cfg, mesh=mesh_cfg,
                       data=DataConfig(seq_len=64 if args.smoke else 4096,
                                       global_batch=mesh_cfg.axis_size(
                                           mesh_cfg.data_axis) * 2),
-                      steps=args.steps)
+                      steps=args.steps,
+                      # full width keeps one layer's activations live
+                      remat="none" if args.smoke else "full")
     cfg = apply_overrides(cfg, args.overrides)
 
     step, state, make_pipeline, _, telemetry, ladder = build_trainer(cfg,
@@ -193,10 +204,15 @@ def main() -> None:
         state, final_step = runner.run(state, 0, cfg.steps)
     dt = time.time() - t0
     losses = [m["loss"] for m in runner.metrics_log]
+    step_s = [m["elapsed"] for m in runner.metrics_log]
     out = {
         "arch": model_cfg.name,
         "steps": final_step,
         "wall_s": round(dt, 2),
+        # the first step includes compilation
+        "first_step_s": round(step_s[0], 4) if step_s else None,
+        "rest_step_s": (round(sum(step_s[1:]) / len(step_s[1:]), 4)
+                        if len(step_s) > 1 else None),
         "first_loss": round(losses[0], 4) if losses else None,
         "last_loss": round(losses[-1], 4) if losses else None,
         "restarts": runner.restarts,
@@ -210,6 +226,13 @@ def main() -> None:
             list(t) for t in ladder.controller.history]
     elif telemetry is not None:
         out["adaptive"] = adaptive_report(cfg, mesh, telemetry)
+    with jax.set_mesh(mesh):
+        state = LS.finalize_state(state, cfg)
+    return out, state
+
+
+def main(argv=None) -> None:
+    out, _ = train(argv)
     print(json.dumps(out))
 
 
