@@ -1,0 +1,46 @@
+"""The yardstick's counts and peak table."""
+import json
+import os
+
+import pytest
+
+from bench import counts, peaks
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def smollm():
+    with open(os.path.join(BENCH, "configs", "smollm-360m.json")) as f:
+        return json.load(f)["config"]
+
+
+def test_smollm_parameter_count():
+    assert counts.lm_param_count(smollm()) == 361_821_120
+
+
+def test_smollm_flops_per_token():
+    c = smollm()
+    matmul = counts.lm_matmul_params(c)
+    # every parameter but the 65 RMSNorm scales multiplies a token once
+    assert matmul == 361_821_120 - 65 * 960
+    attn = 6 * 32 * 4096 * 15 * 64            # causal: half the square
+    f = counts.lm_train_flops_per_token(c, 4096)
+    assert f == 6 * matmul + attn
+    assert f == pytest.approx(2.93e9, rel=2e-3)
+
+
+def test_svm_work_per_sample():
+    assert counts.svm_bytes_per_sample(2000) == 8004
+    assert counts.svm_flops_per_sample(2000) == 8000
+
+
+def test_peaks_known_kind():
+    p = peaks.lookup("TPU v5 lite")
+    assert p["bf16_flops_per_s"] == 197e12
+    assert p["hbm_bytes_per_s"] == 819e9
+    assert p["source"]
+
+
+def test_peaks_unknown_kind_raises():
+    with pytest.raises(KeyError, match="no peaks"):
+        peaks.lookup("TPU v9 imaginary")
