@@ -73,6 +73,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core.telemetry import scope
+
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 # ---------------------------------------------------------------------------
@@ -394,9 +396,10 @@ def _make_worker_block(axis: str, *, c: float, grad_impl: str, overlap: str,
 
     def exchange(v, cnt):
         """Boundary exchange: global mean, or topology neighbor mix."""
-        if gossip:
-            return _sync.gossip_mix(v, axis, topology, round_idx=cnt)
-        return jax.lax.pmean(v, axis)
+        with scope("svm.sync"):
+            if gossip:
+                return _sync.gossip_mix(v, axis, topology, round_idx=cnt)
+            return jax.lax.pmean(v, axis)
 
     def bump(out, carry):
         if gossip and topology == "pairwise" and overlap != "chunked":
@@ -408,35 +411,44 @@ def _make_worker_block(axis: str, *, c: float, grad_impl: str, overlap: str,
         if gossip_async:
             w = carry["w"]
             w_self = _sync.gossip_self_weight(topology)
-            w_end = w - alpha * block_grad(w, xblk, yblk, c, grad_impl)
-            new_w = (w_end + carry["mixbuf"]
-                     + (w_self - 1.0) * carry["sent"])
-            recv = _sync.gossip_recv(new_w, axis, topology, round_idx=cnt)
+            with scope("svm.block"):
+                w_end = w - alpha * block_grad(w, xblk, yblk, c, grad_impl)
+            with scope("svm.sync"):
+                new_w = (w_end + carry["mixbuf"]
+                         + (w_self - 1.0) * carry["sent"])
+                recv = _sync.gossip_recv(new_w, axis, topology,
+                                         round_idx=cnt)
             return bump({"w": new_w, "sent": new_w, "mixbuf": recv}, carry)
         if overlap == "none":
             w = carry["w"]
-            w_local = w - alpha * block_grad(w, xblk, yblk, c, grad_impl)
+            with scope("svm.block"):
+                w_local = w - alpha * block_grad(w, xblk, yblk, c, grad_impl)
             return bump({"w": exchange(w_local, cnt)}, carry)
         if overlap == "delayed":
             w = carry["w"]
-            delta = -alpha * block_grad(w, xblk, yblk, c, grad_impl)
-            w_end = w + delta
+            with scope("svm.block"):
+                delta = -alpha * block_grad(w, xblk, yblk, c, grad_impl)
+                w_end = w + delta
             if gossip:
                 pending = exchange(w_end, cnt) - w_end   # overlappable
             else:
-                pending = jax.lax.pmean(delta, axis) - delta
-            return bump({"w": w_end + carry["pending"],
-                         "pending": pending}, carry)
+                with scope("svm.sync"):
+                    pending = jax.lax.pmean(delta, axis) - delta
+            with scope("svm.block"):
+                w_new = w_end + carry["pending"]
+            return bump({"w": w_new, "pending": pending}, carry)
         # chunked: one w-segment value-exchanged per block
         w = carry["w"]                               # (dp,)
         dp = w.shape[0]
         seg = dp // chunks
-        g = block_grad(w[:d], xblk, yblk, c, grad_impl)
-        w_end = w - alpha * jnp.pad(g, (0, dp - d))
+        with scope("svm.block"):
+            g = block_grad(w[:d], xblk, yblk, c, grad_impl)
+            w_end = w - alpha * jnp.pad(g, (0, dp - d))
         idx = carry["cnt"] % chunks
-        row = jax.lax.dynamic_slice(w_end, (idx * seg,), (seg,))
-        row = exchange(row, carry["cnt"] // chunks)  # 1/chunks of the bytes
-        w_new = jax.lax.dynamic_update_slice(w_end, row, (idx * seg,))
+        with scope("svm.sync"):
+            row = jax.lax.dynamic_slice(w_end, (idx * seg,), (seg,))
+            row = exchange(row, carry["cnt"] // chunks)  # 1/chunks of bytes
+            w_new = jax.lax.dynamic_update_slice(w_end, row, (idx * seg,))
         return {"w": w_new, "cnt": carry["cnt"] + 1}
     return block
 
@@ -472,12 +484,35 @@ def _carry_flush(carry, axis: str, *, overlap: str, d: int,
     """Collapse a worker's carry to the fully synchronized model."""
     if overlap == "none" and topology == "all":
         return carry["w"]
-    if overlap in ("none", "delayed"):
-        # workers sit within one block's drift (delayed) or the gossip
-        # consensus envelope; their mean is the synchronized model (the
-        # mean is invariant under the doubly stochastic gossip mix)
-        return jax.lax.pmean(carry["w"], axis)
-    return jax.lax.pmean(carry["w"], axis)[:d]
+    with scope("svm.sync"):
+        if overlap in ("none", "delayed"):
+            # workers sit within one block's drift (delayed) or the gossip
+            # consensus envelope; their mean is the synchronized model (the
+            # mean is invariant under the doubly stochastic gossip mix)
+            return jax.lax.pmean(carry["w"], axis)
+        return jax.lax.pmean(carry["w"], axis)[:d]
+
+
+def dms_job_counts(n_local: int, d: int, block_size: int, epochs: int,
+                   overlap: str = "none", topology: str = "all",
+                   chunks: int = 4, itemsize: int = 4) -> dict:
+    """What one call of :func:`dms_shard_map_program` does on each worker.
+
+    ``blocks`` local block updates and ``samples`` rows trained (the rows
+    past the last whole block are left out); ``syncs`` exchanges under the
+    ``svm.sync`` scope: one per block, plus the flush's mean wherever the
+    carry is not already the synchronized model (any overlap or gossip);
+    ``sync_bytes`` the values those exchanges take in (a chunked block's
+    one segment, else the whole model), at ``itemsize`` bytes each. An
+    exchange is one all-reduce under ``topology="all"``, one
+    ``ppermute`` a neighbor otherwise."""
+    blocks = epochs * (n_local // block_size)
+    dp = _padded_width(d, chunks) if overlap == "chunked" else d
+    per_block = dp // chunks if overlap == "chunked" else d
+    flush = int(overlap != "none" or topology != "all")
+    return {"blocks": blocks, "syncs": blocks + flush,
+            "sync_bytes": itemsize * (blocks * per_block + flush * dp),
+            "samples": blocks * block_size}
 
 
 @functools.lru_cache(maxsize=64)
@@ -692,7 +727,16 @@ def dms_timed_steps(mesh, axis: str, *, block_size: int, c: float = 1.0,
     else:
         raise ValueError(f"unknown overlap mode: {overlap!r}")
 
-    compute_jit, sync_jit = jax.jit(compute), jax.jit(sync)
+    def scoped(name, fn):
+        @functools.wraps(fn)
+        def run(*args):
+            with scope(name):
+                return fn(*args)
+        return run
+
+    # the same scopes as the fused program's block and exchange
+    compute_jit = jax.jit(scoped("svm.block", compute))
+    sync_jit = jax.jit(scoped("svm.sync", sync))
     if telemetry is None:
         return compute_jit, sync_jit
 

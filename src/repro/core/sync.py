@@ -107,6 +107,7 @@ import numpy as np
 from repro.config.base import SyncConfig
 from repro.core import compression as C
 from repro.core import costmodel
+from repro.core.telemetry import scope
 
 
 def needs_replica_axis(cfg: SyncConfig) -> bool:
@@ -510,25 +511,26 @@ def sync_point(params_start, params_end, sync_state: Dict[str, Any],
     ``param_axes`` — per-leaf logical axes (keeps the compressed-sync
     buffers sharded; see compression.allgather_mean_dequant).
     """
-    if cfg.gossip_async:
-        return _sync_point_gossip_async(params_end, sync_state, cfg, axis)
-    if cfg.topology != "all" and cfg.overlap != "chunked":
-        return _sync_point_gossip(params_end, sync_state, cfg, axis)
-    if cfg.overlap == "delayed":
-        return _sync_point_delayed(params_start, params_end, sync_state,
-                                   cfg, axis, param_axes)
-    if cfg.overlap == "chunked":
-        return _sync_point_chunked(params_end, sync_state, cfg, axis,
-                                   param_axes)
+    with scope("lm.sync"):
+        if cfg.gossip_async:
+            return _sync_point_gossip_async(params_end, sync_state, cfg, axis)
+        if cfg.topology != "all" and cfg.overlap != "chunked":
+            return _sync_point_gossip(params_end, sync_state, cfg, axis)
+        if cfg.overlap == "delayed":
+            return _sync_point_delayed(params_start, params_end, sync_state,
+                                       cfg, axis, param_axes)
+        if cfg.overlap == "chunked":
+            return _sync_point_chunked(params_end, sync_state, cfg, axis,
+                                       param_axes)
 
-    delta = _f32_delta(params_end, params_start)
-    new_state = dict(sync_state)
-    mean_delta, new_ef = _exchange_mean(delta, sync_state.get("ef"), cfg,
-                                        axis, param_axes)
-    if new_ef is not None:
-        new_state["ef"] = new_ef
-    step_delta = _slowmo_step(mean_delta, sync_state, new_state, cfg)
-    return _apply_f32(params_start, step_delta), new_state
+        delta = _f32_delta(params_end, params_start)
+        new_state = dict(sync_state)
+        mean_delta, new_ef = _exchange_mean(delta, sync_state.get("ef"), cfg,
+                                            axis, param_axes)
+        if new_ef is not None:
+            new_state["ef"] = new_ef
+        step_delta = _slowmo_step(mean_delta, sync_state, new_state, cfg)
+        return _apply_f32(params_start, step_delta), new_state
 
 
 def _sync_point_delayed(params_start, params_end, sync_state, cfg, axis,

@@ -1,6 +1,23 @@
-"""Lightweight host-side timing telemetry for the sync schedule.
+"""Host-side timing telemetry, and the names that profiler traces carry.
 
-The MSF auto-tuner (:mod:`repro.core.autotune`) needs two numbers per
+Names. Device scopes and host spans take their names from ``SCOPES`` and
+``SPANS``, all prefixed ``repro.``, and only through :func:`scope`,
+:func:`span` and :func:`step_span`:
+
+* :func:`scope` is a ``jax.named_scope``: every HLO operation traced inside
+  it carries ``repro.<name>`` as one component of its ``op_name`` path.
+  Transforms wrap the path, they do not replace it: a backward or remat
+  op reads ``jit(f)/transpose(jvp(jvp()))/checkpoint/rematted_computation/
+  repro.lm.attention/dot_general``. A fusion carries its root's op_name.
+  Scopes are metadata only: the compiled program is the same without them.
+* :func:`span` and :func:`step_span` are ``jax.profiler`` annotations on
+  the profiler's host clock, which cost next to nothing while no trace is
+  being taken. :class:`repro.runtime.ft.StepRunner` wraps each step in
+  ``repro.step`` (its ``step_num`` is the identifier the step's spans
+  share) and the feed, dispatch, metric fetch, save and ladder hook in
+  spans of their own.
+
+Timers. The MSF auto-tuner (:mod:`repro.core.autotune`) needs two numbers per
 (model × mesh × fabric): ``T_step`` (compute time per optimizer step) and
 ``T_sync`` (one executed sync collective). This module collects both from
 the *running* trainer — jitted code cannot time itself, so the timers wrap
@@ -22,6 +39,37 @@ from the training loop at any block boundary.
 from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
+
+import jax
+
+PREFIX = "repro."
+# device scopes: the SVM's local block update and its model exchange; the
+# LM's attention (projections through ``wo``), MLP, cross-entropy,
+# optimizer update and replica sync
+SCOPES = ("svm.block", "svm.sync", "lm.attention", "lm.mlp", "lm.loss",
+          "lm.optimizer", "lm.sync")
+# host spans of the step loop: ``step`` encloses one step's others
+SPANS = ("step", "data", "dispatch", "fetch", "save", "ladder", "restore")
+
+
+def scope(name: str):
+    """``jax.named_scope("repro." + name)`` for a name in ``SCOPES``."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown scope {name!r}; add it to SCOPES")
+    return jax.named_scope(PREFIX + name)
+
+
+def span(name: str, **ids):
+    """A host span ``repro.<name>`` (a name in ``SPANS``) on the profiler's
+    clock; ``ids`` become the event's stats."""
+    if name not in SPANS:
+        raise ValueError(f"unknown span {name!r}; add it to SPANS")
+    return jax.profiler.TraceAnnotation(PREFIX + name, **ids)
+
+
+def step_span(step: int):
+    """The ``repro.step`` span of one training step, numbered ``step``."""
+    return jax.profiler.StepTraceAnnotation(PREFIX + "step", step_num=step)
 
 
 class EMA:

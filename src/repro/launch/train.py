@@ -215,6 +215,8 @@ def train(argv=None):
                         if len(step_s) > 1 else None),
         "first_loss": round(losses[0], 4) if losses else None,
         "last_loss": round(losses[-1], 4) if losses else None,
+        "steps_run": runner.steps,
+        "saves": runner.saves,
         "restarts": runner.restarts,
         "stragglers": len(runner.watchdog.events),
     }

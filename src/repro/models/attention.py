@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.config.base import ModelConfig
+from repro.core.telemetry import scope
 from repro.models import layers as L
 from repro.sharding import constrain, current_rules
 
@@ -220,27 +221,29 @@ def full_attention(params, x: jax.Array, positions: jax.Array, cfg: ModelConfig,
     ``return_kv=True`` also returns the (post-RoPE) k, v — the prefill path
     stores them directly as the decode cache.
     """
-    q, k, v = _project_qkv(params, x, kv_x, cfg)
-    use_rope = kv_x is None  # no RoPE across enc-dec cross attention
-    if use_rope:
-        cos, sin = rotary_cos_sin(positions, cfg)
-        q = L.apply_rope(q, cos, sin)
-        k = L.apply_rope(k, cos, sin)
-    if impl == "pallas":
-        from repro.kernels.flash_attention import ops as fa_ops
-        out = fa_ops.flash_attention(q, k, v, causal=(mask_mode == "causal"),
-                                     prefix_len=prefix_len if mask_mode == "prefix" else 0)
-    elif q.shape[1] >= _CHUNK_THRESHOLD:
-        out = _sdpa_chunked_jnp(q, k, v, mask_mode, prefix_len)
-    else:
-        mask = make_mask(q.shape[1], k.shape[1], mask_mode, prefix_len)
-        out = _sdpa_jnp(q, k, v, mask)
-    out = constrain(out, "batch", "seq", "heads", "head_dim")
-    y = jnp.einsum("bshd,hdm->bsm", out, params["wo"].astype(x.dtype))
-    y = constrain(y, "batch", "act_seq", "embed")
-    if return_kv:
-        return y, k, v
-    return y
+    with scope("lm.attention"):
+        q, k, v = _project_qkv(params, x, kv_x, cfg)
+        use_rope = kv_x is None  # no RoPE across enc-dec cross attention
+        if use_rope:
+            cos, sin = rotary_cos_sin(positions, cfg)
+            q = L.apply_rope(q, cos, sin)
+            k = L.apply_rope(k, cos, sin)
+        if impl == "pallas":
+            from repro.kernels.flash_attention import ops as fa_ops
+            out = fa_ops.flash_attention(
+                q, k, v, causal=(mask_mode == "causal"),
+                prefix_len=prefix_len if mask_mode == "prefix" else 0)
+        elif q.shape[1] >= _CHUNK_THRESHOLD:
+            out = _sdpa_chunked_jnp(q, k, v, mask_mode, prefix_len)
+        else:
+            mask = make_mask(q.shape[1], k.shape[1], mask_mode, prefix_len)
+            out = _sdpa_jnp(q, k, v, mask)
+        out = constrain(out, "batch", "seq", "heads", "head_dim")
+        y = jnp.einsum("bshd,hdm->bsm", out, params["wo"].astype(x.dtype))
+        y = constrain(y, "batch", "act_seq", "embed")
+        if return_kv:
+            return y, k, v
+        return y
 
 
 def rotary_cos_sin(positions, cfg: ModelConfig):

@@ -12,6 +12,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.telemetry import scope
 from repro.sharding import constrain
 
 
@@ -241,9 +242,10 @@ def mlp_defs(d_model: int, d_ff: int) -> ParamDefs:
 
 def mlp(params, x: jax.Array) -> jax.Array:
     dtype = x.dtype
-    gate = jnp.einsum("bsd,df->bsf", x, params["w_gate"].astype(dtype))
-    up = jnp.einsum("bsd,df->bsf", x, params["w_up"].astype(dtype))
-    h = jax.nn.silu(gate) * up
-    h = constrain(h, "batch", "seq", "mlp")
-    out = jnp.einsum("bsf,fd->bsd", h, params["w_down"].astype(dtype))
-    return constrain(out, "batch", "act_seq", "embed")
+    with scope("lm.mlp"):
+        gate = jnp.einsum("bsd,df->bsf", x, params["w_gate"].astype(dtype))
+        up = jnp.einsum("bsd,df->bsf", x, params["w_up"].astype(dtype))
+        h = jax.nn.silu(gate) * up
+        h = constrain(h, "batch", "seq", "mlp")
+        out = jnp.einsum("bsf,fd->bsd", h, params["w_down"].astype(dtype))
+        return constrain(out, "batch", "act_seq", "embed")

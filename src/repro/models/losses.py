@@ -15,6 +15,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.telemetry import scope
 from repro.sharding import constrain
 
 
@@ -50,26 +51,29 @@ def ce_loss(x: jax.Array, table: jax.Array, targets: jax.Array,
     (e.g. text-only positions for the VLM). ``chunk`` > 0 scans the seq dim
     in slices of that size (must divide S).
     """
-    b, s, d = x.shape
-    valid = jnp.ones((b, s), jnp.float32) if mask is None else mask.astype(jnp.float32)
+    with scope("lm.loss"):
+        b, s, d = x.shape
+        valid = (jnp.ones((b, s), jnp.float32) if mask is None
+                 else mask.astype(jnp.float32))
 
-    if chunk <= 0 or s <= chunk or s % chunk != 0:
-        total, count = _ce_block(x, table, targets, valid)
+        if chunk <= 0 or s <= chunk or s % chunk != 0:
+            total, count = _ce_block(x, table, targets, valid)
+            return total / jnp.maximum(count, 1.0)
+
+        nchunk = s // chunk
+        xs = x.reshape(b, nchunk, chunk, d).swapaxes(0, 1)  # (n, B, C, D)
+        ts = targets.reshape(b, nchunk, chunk).swapaxes(0, 1)
+        vs = valid.reshape(b, nchunk, chunk).swapaxes(0, 1)
+
+        block = jax.checkpoint(
+            lambda xc, tc, vc: _ce_block(xc, table, tc, vc))
+
+        def body(carry, inp):
+            tot, cnt = carry
+            xc, tc, vc = inp
+            t, c = block(xc, tc, vc)
+            return (tot + t, cnt + c), None
+
+        (total, count), _ = _scan(body, (jnp.float32(0), jnp.float32(0)),
+                                  (xs, ts, vs))
         return total / jnp.maximum(count, 1.0)
-
-    nchunk = s // chunk
-    xs = x.reshape(b, nchunk, chunk, d).swapaxes(0, 1)          # (n, B, C, D)
-    ts = targets.reshape(b, nchunk, chunk).swapaxes(0, 1)
-    vs = valid.reshape(b, nchunk, chunk).swapaxes(0, 1)
-
-    block = jax.checkpoint(lambda xc, tc, vc: _ce_block(xc, table, tc, vc))
-
-    def body(carry, inp):
-        tot, cnt = carry
-        xc, tc, vc = inp
-        t, c = block(xc, tc, vc)
-        return (tot + t, cnt + c), None
-
-    (total, count), _ = _scan(body, (jnp.float32(0), jnp.float32(0)),
-                                     (xs, ts, vs))
-    return total / jnp.maximum(count, 1.0)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.base import OptimizerConfig
+from repro.core.telemetry import scope
 
 OptState = Dict[str, Any]
 
@@ -101,7 +102,15 @@ def _maybe_clip(grads, clip: float):
 
 def apply_updates(cfg: OptimizerConfig, grads, state: OptState, params,
                   step: jax.Array, lr: Optional[jax.Array] = None):
-    """Returns (new_params, new_state). ``step`` is the global step counter."""
+    """Returns (new_params, new_state). ``step`` is the global step counter.
+    The whole update, clipping and schedule included, is traced under the
+    ``lm.optimizer`` scope."""
+    with scope("lm.optimizer"):
+        return _apply_updates(cfg, grads, state, params, step, lr)
+
+
+def _apply_updates(cfg: OptimizerConfig, grads, state: OptState, params,
+                   step: jax.Array, lr: Optional[jax.Array]):
     if lr is None:
         lr = make_schedule(cfg)(step)
     grads = _maybe_clip(grads, cfg.grad_clip)

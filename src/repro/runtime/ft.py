@@ -26,6 +26,7 @@ from typing import Any, Callable, Dict, List
 import jax
 
 from repro.config.base import FaultToleranceConfig
+from repro.core.telemetry import span, step_span
 
 
 class SimulatedFault(RuntimeError):
@@ -65,6 +66,17 @@ class StepRunner:
     ``make_pipeline(start_step) -> iterator`` rebuilds the data pipeline at a
     cursor — the restore path uses it to resume data exactly where the
     checkpoint was taken.
+
+    Each step runs inside a ``repro.step`` profiler span numbered by its
+    step, which holds the spans ``data`` (``next(pipeline)``), ``dispatch``
+    (the ``step_fn`` call), ``fetch`` (``block_until_ready`` on the
+    metrics and their ``float()``), ``save`` (``ckpt.save``) and
+    ``ladder`` (``ladder.on_block``); a restore runs in ``restore``.
+    ``metrics_log[i]["elapsed"]`` is the host time from just before the
+    dispatch to the metrics being ready on the device: it leaves out the
+    feed, the ``float()`` of the metrics, the save and the ladder hook.
+    ``steps``, ``saves`` and ``restarts`` count the steps run (a replayed
+    step again), the checkpoints saved and the restores.
     """
 
     def __init__(self, step_fn: Callable, ckpt_manager, fault_cfg: FaultToleranceConfig,
@@ -85,6 +97,8 @@ class StepRunner:
         self.ladder = ladder
         self.watchdog = StragglerWatchdog(fault_cfg.step_deadline_sec)
         self.injector = FaultInjector(fault_cfg)
+        self.steps = 0
+        self.saves = 0
         self.restarts = 0
         self.metrics_log: List[Dict[str, Any]] = []
 
@@ -104,46 +118,57 @@ class StepRunner:
 
     def _run_until(self, state, step: int, end: int, pipeline):
         while step < end:
-            try:
-                batch = next(pipeline)
-            except StopIteration:
-                break
-            self.injector.before_step(step)
-            step_fn = (self.ladder.step_fn if self.ladder is not None
-                       else self.step_fn)
-            t0 = time.perf_counter()
-            state, metrics = step_fn(state, batch)
-            jax.block_until_ready(jax.tree.leaves(metrics))
-            elapsed = time.perf_counter() - t0
-            straggled = self.watchdog.check(step, elapsed)
-            self.metrics_log.append(
-                {"step": step, "elapsed": elapsed, "straggled": straggled,
-                 **{k: float(v) for k, v in metrics.items()}})
-            step += 1
-            if step % self.interval == 0:
-                extra = {"data": pipeline.state()}
+            with step_span(step):
+                try:
+                    with span("data"):
+                        batch = next(pipeline)
+                except StopIteration:
+                    break
+                self.injector.before_step(step)
+                step_fn = (self.ladder.step_fn if self.ladder is not None
+                           else self.step_fn)
+                t0 = time.perf_counter()
+                with span("dispatch"):
+                    state, metrics = step_fn(state, batch)
+                with span("fetch"):
+                    jax.block_until_ready(jax.tree.leaves(metrics))
+                    elapsed = time.perf_counter() - t0
+                    values = {k: float(v) for k, v in metrics.items()}
+                straggled = self.watchdog.check(step, elapsed)
+                self.metrics_log.append(
+                    {"step": step, "elapsed": elapsed,
+                     "straggled": straggled, **values})
+                self.steps += 1
+                step += 1
+                if step % self.interval == 0:
+                    extra = {"data": pipeline.state()}
+                    if self.ladder is not None:
+                        extra["ladder"] = self.ladder.checkpoint_state()
+                    with span("save"):
+                        self.ckpt.save(step, state, extra=extra,
+                                       fingerprint=self.fingerprint)
+                    self.saves += 1
                 if self.ladder is not None:
-                    extra["ladder"] = self.ladder.checkpoint_state()
-                self.ckpt.save(step, state, extra=extra,
-                               fingerprint=self.fingerprint)
-            if self.ladder is not None:
-                state, switched = self.ladder.on_block(state)
-                if switched:
-                    # same microbatch stream, re-blocked at the new H
-                    pipeline = self.make_pipeline(pipeline.state()["step"])
+                    with span("ladder"):
+                        state, switched = self.ladder.on_block(state)
+                    if switched:
+                        # same microbatch stream, re-blocked at the new H
+                        pipeline = self.make_pipeline(
+                            pipeline.state()["step"])
         return state, step, pipeline
 
     def _restore(self, like_state):
-        self.ckpt.wait()
-        latest = self.ckpt.latest_step()
-        if latest is None:
-            # no checkpoint yet — restart from scratch
-            return like_state, 0, self.make_pipeline(0)
-        state, extra = self.ckpt.restore(
-            like_state, expected_fingerprint=self.fingerprint)
-        cursor = int(extra.get("data", {}).get("step", latest))
-        if self.ladder is not None:
-            if "ladder" in extra:
-                self.ladder.restore(extra["ladder"])
-            state = self.ladder.place(state)
-        return state, latest, self.make_pipeline(cursor)
+        with span("restore"):
+            self.ckpt.wait()
+            latest = self.ckpt.latest_step()
+            if latest is None:
+                # no checkpoint yet — restart from scratch
+                return like_state, 0, self.make_pipeline(0)
+            state, extra = self.ckpt.restore(
+                like_state, expected_fingerprint=self.fingerprint)
+            cursor = int(extra.get("data", {}).get("step", latest))
+            if self.ladder is not None:
+                if "ladder" in extra:
+                    self.ladder.restore(extra["ladder"])
+                state = self.ladder.place(state)
+            return state, latest, self.make_pipeline(cursor)

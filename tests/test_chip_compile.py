@@ -8,6 +8,8 @@ or times. The topology is described inside a fixture: only one process at a
 time may load the TPU library, and describing it while a module is
 imported would make test collection differ between pytest workers.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -83,3 +85,12 @@ def test_dms_shard_map_compiles(topo, no_compile_cache, compiled_kernels,
     if chips > 1:
         want = "all-reduce" if topology == "all" else "collective-permute"
         assert want in hlo
+    # the program's scopes survive the chip compiler's passes: the
+    # kernel is the block's, every collective the exchange's
+    lines = [ln for ln in hlo.splitlines() if " = " in ln]
+    assert all("repro.svm.block" in ln for ln in lines
+               if "tpu_custom_call" in ln)
+    colls = [ln for ln in lines if re.search(
+        r"\s(all-reduce|collective-permute)(-start)?\(", ln)]
+    assert all("repro.svm.sync" in ln for ln in colls), colls
+    assert bool(colls) == (chips > 1)
