@@ -10,6 +10,11 @@ Names. Device scopes and host spans take their names from ``SCOPES`` and
   op reads ``jit(f)/transpose(jvp(jvp()))/checkpoint/rematted_computation/
   repro.lm.attention/dot_general``. A fusion carries its root's op_name.
   Scopes are metadata only: the compiled program is the same without them.
+* :data:`ATTENTION` counts, while a program is traced, the training /
+  prefill attention calls by the path each takes (``flash``, the fused
+  TPU kernel; ``chunked`` and ``full``, the jnp paths; ``pallas``, the
+  forward-only kernel a caller selects). A layer scan traces its body
+  once; the scan counts it once per layer (:meth:`PathCounts.repeated`).
 * :func:`span` and :func:`step_span` are ``jax.profiler`` annotations on
   the profiler's host clock, which cost next to nothing while no trace is
   being taken. :class:`repro.runtime.ft.StepRunner` wraps each step in
@@ -38,6 +43,7 @@ from the training loop at any block boundary.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -70,6 +76,46 @@ def span(name: str, **ids):
 def step_span(step: int):
     """The ``repro.step`` span of one training step, numbered ``step``."""
     return jax.profiler.StepTraceAnnotation(PREFIX + "step", step_num=step)
+
+
+class PathCounts:
+    """Trace-time count of calls by the path each took.
+
+    :meth:`add` runs in the Python body of a traced function, so it counts
+    traces, not executions. Inside :meth:`repeated` each call counts ``n``
+    times: a ``lax.scan`` over ``n`` layers traces its body once.
+    """
+
+    def __init__(self, paths: Tuple[str, ...]):
+        self.paths = paths
+        self._counts = dict.fromkeys(paths, 0)
+        self._times = 1
+
+    def add(self, path: str) -> None:
+        if path not in self._counts:
+            raise ValueError(f"unknown path {path!r}; one of {self.paths}")
+        self._counts[path] += self._times
+
+    @contextlib.contextmanager
+    def repeated(self, n: int):
+        """Count each call inside as ``n`` calls."""
+        prev = self._times
+        self._times = prev * n
+        try:
+            yield
+        finally:
+            self._times = prev
+
+    def counts(self) -> Dict[str, int]:
+        return dict(self._counts)
+
+    def since(self, before: Dict[str, int]) -> Dict[str, int]:
+        """Calls counted since ``before`` (an earlier :meth:`counts`)."""
+        return {p: n - before.get(p, 0) for p, n in self._counts.items()}
+
+
+# attention calls by path (``models/attention.py::full_attention``)
+ATTENTION = PathCounts(("flash", "chunked", "full", "pallas"))
 
 
 class EMA:

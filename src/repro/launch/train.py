@@ -25,6 +25,7 @@ from repro.config import TrainConfig, config_fingerprint, get_arch, get_smoke
 from repro.config.cli import apply_overrides, build_parser
 from repro.core import local_sgd as LS
 from repro.core import sync as SY
+from repro.core.telemetry import ATTENTION
 from repro.data.pipeline import DataPipeline
 from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_test_mesh, test_mesh_config
@@ -192,6 +193,7 @@ def train(argv=None):
                       remat="none" if args.smoke else "full")
     cfg = apply_overrides(cfg, args.overrides)
 
+    traced_before = ATTENTION.counts()
     step, state, make_pipeline, _, telemetry, ladder = build_trainer(cfg,
                                                                      mesh)
     ckpt = CheckpointManager(cfg.checkpoint)
@@ -219,6 +221,8 @@ def train(argv=None):
         "saves": runner.saves,
         "restarts": runner.restarts,
         "stragglers": len(runner.watchdog.events),
+        # attention layers traced in this run's programs, by path
+        "attention_calls": ATTENTION.since(traced_before),
     }
     if ladder is not None:
         # the live H-ladder run: trajectory, switches, per-rung telemetry

@@ -3,8 +3,13 @@
 Two distribution regimes:
 
 * **train / prefill** — full-sequence attention; activations sharded
-  ``batch→data, heads→model`` via logical constraints; optional Pallas
-  flash-attention kernel on TPU (``impl="pallas"``), jnp oracle otherwise.
+  ``batch→data, heads→model`` via logical constraints. Causal
+  self-attention at a lane-aligned length on TPU devices, where the mesh
+  gives each chip a batch or head shard of its own, runs the fused flash
+  kernel with its backward (splash attention, :func:`_sdpa_flash`);
+  everything else runs the jnp paths (:func:`_sdpa_jnp`, and
+  :func:`_sdpa_chunked_jnp` from ``_CHUNK_THRESHOLD``). ``impl="pallas"``
+  selects the forward-only Pallas kernel.
 * **decode** — the KV cache is sharded along *sequence* over the model axis
   (``cache_seq`` rule). A partial-manual ``shard_map`` computes blockwise
   attention per shard and merges with a log-sum-exp ``psum`` — a distributed
@@ -13,20 +18,19 @@ Two distribution regimes:
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 from jax.sharding import PartitionSpec as P
 
+from repro import flags as _flags, kernels as _kernels
 from repro.config.base import ModelConfig
-from repro.core.telemetry import scope
+from repro.core.telemetry import ATTENTION, scope
 from repro.models import layers as L
 from repro.sharding import constrain, current_rules
-
-
-
-from repro import flags as _flags
 
 
 def _scan(*args, **kw):
@@ -211,6 +215,127 @@ def _sdpa_chunked_jnp(q, k, v, mask_mode: str, prefix_len: int,
     return outs.transpose(1, 0, 2, 3, 4).reshape(b, s, h, hd)
 
 
+# the flash kernel's shapes: a sequence of whole 128-lane tiles, from 512
+# on (below it the jnp path was faster on a v5e, PERF.md); a head no wider
+# than the kernel's VMEM tiles allow
+_FLASH_MIN_SEQ = 512
+_FLASH_MAX_HEAD_DIM = 256
+
+
+def _on_tpu() -> bool:
+    """Whether the devices the computation is placed on are TPUs: the
+    current rules' mesh if there is one, else the process' devices."""
+    rules = current_rules()
+    mesh = rules.mesh if rules is not None else None
+    devices = mesh.devices.flat if mesh is not None else jax.devices()
+    return all(d.platform == "tpu" for d in devices)
+
+
+def _attention_path(q_shape: Tuple[int, ...], kv_heads: int,
+                    mask_mode: str, cross: bool) -> str:
+    """``flash`` for causal self-attention at a lane-aligned length on
+    TPUs, where each chip's kernel call gets a shard of its own
+    (:func:`_flash_spec`); else ``chunked`` from ``_CHUNK_THRESHOLD`` on,
+    ``full`` below."""
+    b, seq_len, h, head_dim = q_shape
+    if (mask_mode == "causal" and not cross and seq_len % 128 == 0
+            and seq_len >= _FLASH_MIN_SEQ and head_dim <= _FLASH_MAX_HEAD_DIM
+            and _on_tpu() and _flash_spec(b, h, kv_heads) is not None):
+        return "flash"
+    return "chunked" if seq_len >= _CHUNK_THRESHOLD else "full"
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_plan(seq_len: int, group: int):
+    """The causal mask over ``group`` query heads and the block sizes of
+    the splash kernel at ``seq_len``: q and kv blocks of the largest of
+    1024, 512, 256, 128 dividing ``seq_len``, kv computed 512 at a time,
+    and the fused dq/dkv backward (the fastest of the settings compared
+    on a v5e, PERF.md). Splash caches a mask's block tables by these
+    objects, so the kernel each trace makes from them reuses the tables;
+    fully masked blocks are skipped in both passes."""
+    blk = next(b for b in (1024, 512, 256, 128) if seq_len % b == 0)
+    sizes = splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=min(blk, 512),
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+        use_fused_bwd_kernel=True)
+    mask = splash.MultiHeadMask(
+        [splash.CausalMask((seq_len, seq_len))] * group)
+    return mask, sizes
+
+
+def _flash_spec(b: int, h: int, kv: int) -> Optional[P]:
+    """The (B, S, heads, hd) spec of each chip's kernel call under the
+    current rules' mesh (``P()`` with none): batch over its mesh axes,
+    heads over theirs where whole KV groups divide them. ``None`` where a
+    mesh axis of more than one device, not manual already, carries
+    neither: the compiler cannot split the kernel, so every chip on that
+    axis would run the same attention. The jnp paths split it there
+    (flat heads, or the q rows over ``act_seq``)."""
+    rules = current_rules()
+    if rules is None or rules.mesh is None:
+        return P()
+    groups = (rules.would_shard("kv_heads", kv)
+              and rules.would_shard("heads", h)
+              and rules.mesh_axes_for("kv_heads")
+              == rules.mesh_axes_for("heads"))
+    spec = rules.spec_for(("batch", None, "kv_heads" if groups else None),
+                          (b, 1, kv))
+    split = set(jax.sharding.get_abstract_mesh().manual_axes)
+    for entry in spec:
+        split.update((entry,) if isinstance(entry, str) else entry or ())
+    if any(n > 1 and a not in split for a, n in rules.mesh.shape.items()):
+        return None
+    return spec
+
+
+def _sdpa_flash(q, k, v, interpret: Optional[bool] = None) -> jax.Array:
+    """Causal GQA attention through the fused splash kernel.
+
+    q: (B,S,H,hd) · k/v: (B,S,KV,hd) → (B,S,H,hd). One vmap over the
+    B·KV (batch, KV head) pairs, each call attending G = H/KV query heads
+    to one shared KV head (never repeated); a vmap over batch and another
+    over KV heads left ≈ 10 ms more copy waits in each step on a v5e
+    (PERF.md). bf16 operands feed the matrix unit with float32
+    accumulation and a float32 softmax. On a mesh the call is manual
+    over every axis (``shard_map``): the compiler cannot partition the
+    kernel, so each chip runs it on its own batch and head shard.
+    """
+    b, s, h, hd = q.shape
+    kv = k.shape[2]
+    g = h // kv
+    if interpret is None:
+        interpret = _kernels.default_interpret()
+    mask, sizes = _splash_plan(s, g)
+    kernel = splash.make_splash_mqa_single_device(
+        mask, block_sizes=sizes, interpret=interpret)
+    q = q * jnp.asarray(hd ** -0.5, q.dtype)       # splash does not scale
+
+    def attend(q, k, v):
+        bl, kvl = q.shape[0], k.shape[2]
+        n = bl * kvl
+
+        def by_kv_head(x):                  # (B,S,KV,hd) → (B·KV,S,hd)
+            return x.transpose(0, 2, 1, 3).reshape(n, s, hd)
+
+        qg = q.reshape(bl, s, kvl, g, hd).transpose(0, 2, 3, 1, 4)
+        o = jax.vmap(kernel)(qg.reshape(n, g, s, hd), by_kv_head(k),
+                             by_kv_head(v))
+        o = o.reshape(bl, kvl, g, s, hd).transpose(0, 3, 1, 2, 4)
+        return o.reshape(bl, s, kvl * g, hd)
+
+    rules = current_rules()
+    if rules is None or rules.mesh is None:
+        return attend(q, k, v)
+    spec = _flash_spec(b, h, kv)
+    context = jax.sharding.get_abstract_mesh()
+    # under the local-SGD block the replica axis is manual already
+    manual = set(rules.mesh.axis_names) - set(context.manual_axes)
+    return jax.shard_map(attend, mesh=rules.mesh if context.empty else None,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         axis_names=manual, check_vma=False)(q, k, v)
+
+
 def full_attention(params, x: jax.Array, positions: jax.Array, cfg: ModelConfig,
                    mask_mode: str = "causal", prefix_len: int = 0,
                    kv_x: Optional[jax.Array] = None,
@@ -228,12 +353,17 @@ def full_attention(params, x: jax.Array, positions: jax.Array, cfg: ModelConfig,
             cos, sin = rotary_cos_sin(positions, cfg)
             q = L.apply_rope(q, cos, sin)
             k = L.apply_rope(k, cos, sin)
-        if impl == "pallas":
+        path = ("pallas" if impl == "pallas" else _attention_path(
+            q.shape, k.shape[2], mask_mode, cross=kv_x is not None))
+        ATTENTION.add(path)
+        if path == "pallas":
             from repro.kernels.flash_attention import ops as fa_ops
             out = fa_ops.flash_attention(
                 q, k, v, causal=(mask_mode == "causal"),
                 prefix_len=prefix_len if mask_mode == "prefix" else 0)
-        elif q.shape[1] >= _CHUNK_THRESHOLD:
+        elif path == "flash":
+            out = _sdpa_flash(q, k, v)
+        elif path == "chunked":
             out = _sdpa_chunked_jnp(q, k, v, mask_mode, prefix_len)
         else:
             mask = make_mask(q.shape[1], k.shape[1], mask_mode, prefix_len)

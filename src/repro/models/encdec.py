@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.base import ModelConfig
+from repro.core.telemetry import ATTENTION
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models.losses import ce_loss
@@ -93,7 +94,8 @@ class EncDecModel:
 
         if self.remat != "none":
             body = jax.checkpoint(body)
-        x, _ = _scan(body, x, params["enc_layers"])
+        with ATTENTION.repeated(cfg.n_encoder_layers):
+            x, _ = _scan(body, x, params["enc_layers"])
         return L.apply_norm(params["enc_norm"], x, cfg.norm_type, cfg.norm_eps)
 
     # -------------------------------------------------------------- decoder
@@ -135,7 +137,8 @@ class EncDecModel:
 
         if self.remat != "none" and not return_cache:
             body = jax.checkpoint(body)
-        x, kvs = _scan(body, x, params["dec_layers"])
+        with ATTENTION.repeated(cfg.n_layers):
+            x, kvs = _scan(body, x, params["dec_layers"])
         x = L.apply_norm(params["final_norm"], x, cfg.norm_type, cfg.norm_eps)
         return (x, kvs) if return_cache else x
 

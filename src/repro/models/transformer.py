@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.base import ModelConfig
+from repro.core.telemetry import ATTENTION
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as M
@@ -161,7 +162,8 @@ class DecoderLM:
             return x, (aux,)
 
         if self.scan_layers:
-            x, ys = _scan(scan_body, x, params["layers"])
+            with ATTENTION.repeated(cfg.n_layers):
+                x, ys = _scan(scan_body, x, params["layers"])
         else:
             ys_list = []
             for i in range(cfg.n_layers):
