@@ -43,3 +43,15 @@ def svm_flops_per_sample(d: int) -> int:
     """The margin's dot product and the row's share of the block
     gradient: 2·d each."""
     return 4 * d
+
+
+def svm_job_counts(n_local: int, block_size: int, epochs: int,
+                   overlap: str = "none", topology: str = "all") -> dict:
+    """What one DMS job does on each worker: ``blocks`` local block
+    updates (the rows past the last whole block are left out), and
+    ``syncs`` exchanges under the program's ``svm.sync`` scope, one a
+    block plus the flush's mean wherever the carry is not already the
+    synchronized model (any overlap, or a gossip topology)."""
+    blocks = epochs * (n_local // block_size)
+    flush = int(overlap != "none" or topology != "all")
+    return {"blocks": blocks, "syncs": blocks + flush}

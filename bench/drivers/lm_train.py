@@ -14,7 +14,8 @@ The window then runs one step after another, each through
 ``StepRunner.run`` (the runner blocks on every step and fetches its
 metrics), until ``--seconds`` have passed. Each batch the pipeline hands
 out and each step carry host spans (``bench.data``, ``bench.step_fn``)
-for the trace.
+for the trace. With ``--trace 1`` a few steps are profiled, and the
+step's compiled text is read after the window.
 
 After the window the trainer's state is freed and the plain reference
 (``bench.references.lm_ref``) trains the same weights on the same three
@@ -252,7 +253,10 @@ def run(ctx):
     step, state, make_pipeline, cfg, mesh = _trainer(ctx, ckpt_dir)
     state, make_params = _own_weights(ctx, state)
 
+    fed = {}
+
     def step_fn(st, batch):
+        fed["batch"] = batch
         with tr.span("step_fn"):
             return step(st, batch)
 
@@ -281,7 +285,14 @@ def run(ctx):
                 losses=losses.tolist())
         state, done, window_s, trace_dir, traced = _window(ctx, runner,
                                                            state, n)
-    compiles = counter.since_mark
+        compiles = counter.since_mark
+        programs = None
+        if trace_dir is not None:
+            # the step as it ran: the same jit, mesh and kinds of argument,
+            # so its executable comes from memory, as set-up compiled or
+            # loaded it
+            programs = [step.lower(state, fed["batch"]).compile().as_text()]
+            ctx.log(phase="programs", compiles=counter.since_mark - compiles)
     peaks = {str(d.id): int((d.memory_stats() or {})
                             .get("peak_bytes_in_use", 0))
              for d in ctx.devices}
@@ -291,14 +302,10 @@ def run(ctx):
             compiles_in_window=compiles, peak_bytes_in_use=peaks,
             last_loss=window_losses[-1],
             step_s=[m["elapsed"] for m in runner.metrics_log[n_check:]])
-    summary = None
-    if trace_dir is not None:
-        summary = tr.summarize(tr.load(tr.find_xplane(trace_dir)))
-        shutil.rmtree(trace_dir, ignore_errors=True)
     shutil.rmtree(ckpt_dir, ignore_errors=True)
 
     # -- check: free the trainer, then the reference on the same batches
-    del state, runner, step, step_fn, make_params
+    del state, runner, step, step_fn, make_params, fed
     gc.collect()
     t_ref = time.perf_counter()
     ref = reference_readings(ctx)
@@ -325,4 +332,4 @@ def run(ctx):
         counts={"traced_steps": traced, "traced_tokens": traced * b * s,
                 "compiles_in_window": compiles},
         checks=checks, memory_peak_bytes=max(peaks.values()),
-        trace_summary=summary)
+        trace_dir=trace_dir, programs=programs)

@@ -5,7 +5,8 @@ Set-up makes each worker's rows on its own chip from the seed, builds and
 compiles the program and runs one job. The window then runs whole jobs
 back to back, each ending in ``block_until_ready`` on its weights, until
 ``--seconds`` have passed; the rate counts every training sample of every
-worker. With ``--trace 1`` a few jobs in the window are profiled.
+worker. With ``--trace 1`` a few jobs in the window are profiled, and the
+program's compiled text is read after them.
 
 The check, after the window: every job's weights against the plain
 reference (``bench.references.svm_ref.dms``) on the same rows, computed on
@@ -15,7 +16,6 @@ beside it.
 """
 from __future__ import annotations
 
-import shutil
 import tempfile
 import time
 
@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from bench import datagen, trace as tr
+from bench import counts, datagen, trace as tr
 from bench.references import svm_ref
 
 
@@ -110,6 +110,8 @@ def run(ctx):
     t = ctx.cell["traffic_params"]
     k, n_local, d, epochs, block = _shape(ctx)
     samples_per_job = epochs * k * (n_local // block) * block
+    job = counts.svm_job_counts(n_local, block, epochs, t["overlap"],
+                                t["topology"])
 
     counter = CompileCounter().install()
     mesh = Mesh(np.array(ctx.devices), (t["axis"],))
@@ -158,10 +160,12 @@ def run(ctx):
     jobs = len(outs)
     ctx.log(phase="window", jobs=jobs, window_s=window_s,
             compiles_in_window=compiles, peak_bytes_in_use=peaks)
-    summary = None
+    programs = None
     if trace_dir is not None:
-        summary = tr.summarize(tr.load(tr.find_xplane(trace_dir)))
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        # the program as it ran: the same call, so the executable comes
+        # from memory, as set-up compiled or loaded it
+        programs = [fn.lower(w0, xs, ys).compile().as_text()]
+        ctx.log(phase="programs", compiles=counter.since_mark - compiles)
 
     # -- check: the reference on one chip, after the window
     t_ref = time.perf_counter()
@@ -186,6 +190,8 @@ def run(ctx):
                     "setup_s": setup_s},
         counts={"traced_samples": traced_jobs * samples_per_job,
                 "traced_jobs": traced_jobs,
+                "traced_blocks": traced_jobs * job["blocks"],
+                "traced_syncs": traced_jobs * job["syncs"],
                 "compiles_in_window": compiles},
         checks=checks, memory_peak_bytes=max(peaks.values()),
-        trace_summary=summary)
+        trace_dir=trace_dir, programs=programs)

@@ -5,7 +5,7 @@ from bench import counts
 
 
 def read(r):
-    if not r.counts.get("traced_samples"):
+    if not r.counts.get("traced_samples") or r.summary is None:
         return None
     rate = r.counts["traced_samples"] / r.summary["window_s"]
     d = r.config["features"]

@@ -6,7 +6,7 @@ from bench import counts
 
 
 def read(r):
-    if not r.counts.get("traced_samples"):
+    if not r.counts.get("traced_samples") or r.summary is None:
         return None
     busy = sum(d["other_s"] for d in r.summary["per_device"].values())
     if busy <= 0:

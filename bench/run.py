@@ -12,11 +12,16 @@ The first work is a look for the chips: with no TPU, or fewer chips than
 the cell asks for, the run exits non-zero and prints no result. A driver
 sets up (data, weights and state made on the device from the seed,
 compiles, warm-up), measures for ``--seconds``, then checks what the
-timed path produced against a plain reference. Earlier lines of standard
-output carry the driver's records; the last is one JSON object with
-``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` (and
-``breakdown`` with ``--trace 1``) and, last, ``check``: each number
-compared beside its limit, which also ends standard error.
+timed path produced against a plain reference. With ``--trace 1`` it
+profiles a few units of work inside the window and hands over the profile
+and, read after the traced stretch, the HLO text of the programs that ran
+in it; the harness reduces them to the summary the per-layer readers get.
+A reader returns nothing where it finds nothing to read, and its metric
+is then left out of the line. Earlier lines of standard output carry the
+driver's records; the last is one JSON object with ``correct``,
+``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown``
+with ``--trace 1``) and, last, ``check``: each number compared beside
+its limit, which also ends standard error.
 """
 from __future__ import annotations
 
@@ -29,6 +34,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import shutil
 import sys
 from typing import Any, Callable, Dict, List, Optional
 
@@ -98,7 +104,10 @@ class Result:
     counts: Dict[str, Any]            # for the per-layer readers
     checks: List[dict]                # {"name", "value", "limit"}
     memory_peak_bytes: int
-    trace_summary: Optional[dict] = None
+    # with --trace 1: the profile of the traced stretch, and the compiled
+    # HLO text of each program that ran in it, read after that stretch
+    trace_dir: Optional[str] = None
+    programs: Optional[List[str]] = None
 
 
 @dataclasses.dataclass
@@ -126,6 +135,24 @@ def cell_metrics(spec: dict, name: str):
              if name in m.get("workloads", [name] if m["moves"] in names
                               else [])]
     return e2e, layer
+
+
+def summarize_trace(trace_dir: str, programs: Optional[List[str]]) -> dict:
+    """The summary the per-layer readers get: ``bench.trace.summarize``'s,
+    and, where the driver handed the programs' text, ``scopes``,
+    ``host_spans`` and ``unmapped`` (``bench.scopes.layer_summary``).
+    The profile is removed once read."""
+    from bench import scopes as sc, trace as tr
+    try:
+        path = tr.find_xplane(trace_dir)
+        plain = tr.load(path)
+        summary = tr.summarize(plain)
+        if programs is not None:
+            summary.update(sc.layer_summary(sc.scoped(plain, path,
+                                                      programs)))
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    return summary
 
 
 def check_ok(c: dict) -> bool:
@@ -165,7 +192,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool) -> dict:
     metrics = {}
     breakdown = None
     if trace:
-        s = res.trace_summary
+        s = summarize_trace(res.trace_dir, res.programs)
+        log(phase="trace", busy_s=s["mean"]["busy_s"],
+            unmapped_s=s.get("unmapped"), scopes=s.get("scopes"),
+            host_spans=s.get("host_spans"))
         device["busy_s"] = s["mean"]["busy_s"]
         device["window_s"] = s["window_s"]
         reading = Reading(s, res.counts, peaks_mod.lookup(

@@ -1,24 +1,26 @@
-"""Run a cell as ``bench/run.py`` runs it with ``--trace 1``, and read its
-trace by the names the program gives its work.
+"""Run a cell as ``bench/run.py`` runs it with ``--trace 1``, and keep its
+trace as read by the names the program gives its work.
 
     python3 bench/scope_report.py --workload <cell> --seed <n>
                                   [--seconds 10] [--record FILE]
+                                  [--gaps FILE]
 
 The run is ``bench.run.run_cell``'s, untouched: the same driver, window,
-traced stretch and check. Two things are kept on the side. Every program
-compiled in the run gives its HLO text (the process compiles them all:
-the persistent compile cache is off, so the text is the compiler's own).
-And when the driver loads its trace with ``bench.trace.load``, the same
-trace is read by scope (``bench.scopes.scoped``).
+traced stretch, check and per-layer metrics, the readings by scope among
+them. The trace, as the harness reads it by scope
+(``bench.scopes.scoped``), is kept on the side.
 
 The one JSON line printed holds the cell's result line (``result``, as
 ``bench/run.py`` prints it), the job or step accounting (``per_unit``),
-what one traced unit reads per scope (``readings``), how far each device
-module run lies outside its host interval once the clocks are aligned
-(``outside_units``), the trace file's size and the scope summary.
-``--record`` writes a trimmed ``bench.scopes.Scoped`` for the tests:
-eight whole SVM blocks from the middle of the first traced job, or the
-longest operations of each scope in one LM step with that step's spans.
+how far each device module run lies outside its host interval once the
+clocks are aligned (``outside_units``), the trace file's size and the
+whole scope summary (``bench.scopes.summarize``: op counts and exchanges
+by scope beside the seconds). ``--record`` writes a trimmed
+``bench.scopes.Scoped`` for the tests: eight whole SVM blocks from the
+middle of the first traced job, or the longest operations of each scope
+in one LM step with that step's spans. ``--gaps`` writes, as JSON, every
+device idle gap over 0.1 ms of the traced stretch, longest first, with
+the operations on either side of it and the module run it falls inside.
 """
 from __future__ import annotations
 
@@ -35,46 +37,23 @@ for p in (os.path.join(ROOT, "src"), ROOT):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+from bench import counts
 from bench import run as R
 from bench import scopes as sc
 from bench import trace as tr
 
 
-def _hlo_text(exe) -> str:
-    """A loaded executable's HLO text with its metadata, as
-    ``jax.stages.Compiled.as_text`` reads it."""
-    if hasattr(exe, "get_hlo_text"):
-        return exe.get_hlo_text()
-    return "\n\n".join(m.to_string() for m in exe.hlo_modules())
-
-
 def per_unit(cell: dict, config: dict) -> dict:
     """What one traced unit does: an SVM job's blocks and exchanges on
-    each worker (``repro.core.svm.dms_job_counts``), or one LM step."""
+    each worker (``bench.counts.svm_job_counts``), or one LM step."""
     t = cell["traffic_params"]
     if config["driver"] == "svm_dms":
-        from repro.core import svm
         n_local = int(config["samples"] * config["split"]["train"]) \
             // cell["chips"]
-        return svm.dms_job_counts(
-            n_local, config["features"], t["block_per_worker"],
-            t["epochs_per_job"], overlap=t["overlap"],
-            topology=t["topology"])
+        return counts.svm_job_counts(
+            n_local, t["block_per_worker"], t["epochs_per_job"],
+            t["overlap"], t["topology"])
     return {"steps": 1, "tokens": t["global_batch"] * t["seq_len"]}
-
-
-def readings(summary: dict, per: dict, units: int) -> dict:
-    """What one unit reads by the program's names: SVM microseconds a
-    sync and a block, LM milliseconds a step."""
-    s, h = summary["scopes"], summary["host_spans"]
-    if "syncs" in per:
-        return {"svm_sync_us": s.get("svm.sync", 0.0)
-                / (units * per["syncs"]) * 1e6,
-                "svm_block_us": s.get("svm.block", 0.0)
-                / (units * per["blocks"]) * 1e6}
-    return {"attn_ms": s.get("lm.attention", 0.0) / units * 1e3,
-            "opt_ms": s.get("lm.optimizer", 0.0) / units * 1e3,
-            "dispatch_ms": h.get("dispatch", 0.0) / units * 1e3}
 
 
 def trim_svm(t: sc.Scoped, blocks: int = 8) -> sc.Scoped:
@@ -122,58 +101,62 @@ def _cut(t: sc.Scoped, lo: int, hi: int, pick) -> sc.Scoped:
         sorted(spans, key=lambda s: s[1]))
 
 
+def gaps(t: sc.Scoped, least_ns: int = 100_000) -> list:
+    """Each device's idle gaps longer than ``least_ns``, longest first:
+    the ms, the operations before and after, and the module run the gap
+    lies inside (empty between runs)."""
+    out = []
+    for dev, ops in t.devices.items():
+        runs = t.modules.get(dev, [])
+        end, prev = None, None
+        for op in sorted(ops, key=lambda o: o[1]):
+            if end is not None and op[1] - end > least_ns:
+                inside = [r[0] for r in runs if r[1] <= end and op[1] <= r[2]]
+                out.append({"dev": dev, "ms": (op[1] - end) / 1e6,
+                            "after": str(prev[0])[:90],
+                            "before": str(op[0])[:90],
+                            "in_module_run": inside[:1]})
+            if end is None or op[2] > end:
+                end, prev = op[2], op
+    return sorted(out, key=lambda g: -g["ms"])
+
+
 def report(name: str, seed: int, seconds: float = 10.0,
-           record=None) -> dict:
-    import jax
-    from jax._src import compiler
+           record=None, gaps_to=None) -> dict:
+    kept = {}
+    scoped = sc.scoped
 
-    texts, kept = [], {}
-    compile_program = compiler.compile_or_get_cached
-    load_trace = tr.load
-
-    def compile_and_keep(*args, **kwargs):
-        exe = compile_program(*args, **kwargs)
-        texts.append(_hlo_text(exe))
-        return exe
-
-    def load_and_keep(path):
-        plain = load_trace(path)
-        kept["scoped"] = sc.scoped(plain, path, texts)
+    def keep(plain, path, texts):
+        kept["scoped"] = out = scoped(plain, path, texts)
         kept["bytes"] = os.path.getsize(path)
-        return plain
+        return out
 
-    cache = jax.config.jax_enable_compilation_cache
-    compiler.compile_or_get_cached = compile_and_keep
-    tr.load = load_and_keep
+    sc.scoped = keep
     try:
-        jax.config.update("jax_enable_compilation_cache", False)
         result = R.run_cell(name, seed, seconds, True)
     finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
-        compiler.compile_or_get_cached = compile_program
-        tr.load = load_trace
+        sc.scoped = scoped
     cell = R.load_json(R.BENCH, "workloads", name + ".json")
     config = R.load_json(R.BENCH, "configs", cell["config"] + ".json")
     is_svm = config["driver"] == "svm_dms"
     units = cell["traffic_params"]["traced_jobs" if is_svm
                                    else "traced_steps"]
-    scoped = kept["scoped"]
-    summary = sc.summarize(scoped)
-    per = per_unit(cell, config)
+    t = kept["scoped"]
     if is_svm:
-        inside = [s[1:] for s in scoped.spans
-                  if s[0] == tr.SPAN_PREFIX + "job"]
+        inside = [s[1:] for s in t.spans if s[0] == tr.SPAN_PREFIX + "job"]
     else:
-        inside = sc.dispatch_to_fetch(scoped.spans)
+        inside = sc.dispatch_to_fetch(t.spans)
     if record:
-        cut = (trim_svm if is_svm else trim_lm)(scoped)
+        cut = (trim_svm if is_svm else trim_lm)(t)
         with open(record, "w") as f:
             f.write(cut.to_json())
+    if gaps_to:
+        with open(gaps_to, "w") as f:
+            json.dump(gaps(t), f, indent=0)
     return {"workload": name, "seed": seed, "result": result,
-            "units": units, "per_unit": per,
-            "readings": readings(summary, per, units),
-            "outside_units": sc.outside_units(scoped, inside),
-            "trace_bytes": kept["bytes"], "summary": summary}
+            "units": units, "per_unit": per_unit(cell, config),
+            "outside_units": sc.outside_units(t, inside),
+            "trace_bytes": kept["bytes"], "summary": sc.summarize(t)}
 
 
 def main(argv=None) -> int:
@@ -182,9 +165,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, default=10.0)
     ap.add_argument("--record")
+    ap.add_argument("--gaps")
     args = ap.parse_args(argv)
     print(json.dumps(report(args.workload, args.seed, args.seconds,
-                            args.record)), flush=True)
+                            args.record, args.gaps)), flush=True)
     return 0
 
 

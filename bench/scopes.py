@@ -35,6 +35,10 @@ scope of the instruction behind it, checking that the profile holds the
 same operations in the same order; each device's module runs are moved
 onto the host's clock by the shift ``load`` gave that device's operations.
 A collective is what ``bench.trace.is_collective`` calls one.
+
+``bench/run.py`` adds :func:`layer_summary` of every traced run, read
+from the program texts its driver hands over, to the summary that the
+per-layer readers get.
 """
 from __future__ import annotations
 
@@ -56,6 +60,9 @@ _INSTR = re.compile(r"^\s*(?:ROOT )?%([^\s=]+) = .*?\s([a-z][\w\-]*)\(")
 _OP_NAME = re.compile(r'metadata=\{[^{}]*op_name="([^"]*)"')
 _HEAD = re.compile(r"^%?([^\s=]+)")
 _RUN_ID = re.compile(r"\(\d+\)$")
+# a multi-line ``{…}}`` attribute block, as a Mosaic kernel's
+# ``kernel_metadata`` runs over three lines ahead of its op_name
+_BLOCK = re.compile(r"\{\n([^\n]*)\n\}\}")
 
 Event = tr.Event
 # an operation: its name as ``bench.trace.load`` gives it, start and end
@@ -71,13 +78,22 @@ def scope_of(op_name: str) -> str:
     return found[-1][len(PREFIX):] if found else ""
 
 
+def joined(text: str) -> str:
+    """``text`` with each multi-line ``{…}}`` attribute block on the line
+    of the instruction it belongs to."""
+    return _BLOCK.sub(r"{\1}}", text)
+
+
 def op_table(texts: Iterable[str]
              ) -> Dict[str, Dict[str, Tuple[str, Optional[str]]]]:
-    """Module → instruction → (kind, scope), from compiled HLO text. Where
-    two texts name the same module and disagree on an instruction, its
-    scope is ``None``: it cannot be told which of them ran."""
+    """Module → instruction → (kind, scope), from compiled HLO text, each
+    instruction read from its line once multi-line attribute blocks are
+    :func:`joined` onto it (so a Mosaic kernel, such as the splash
+    attention kernels, reads its scope). Where two texts name the same
+    module and disagree on an instruction, its scope is ``None``: it
+    cannot be told which of them ran."""
     table: Dict[str, Dict[str, Tuple[str, Optional[str]]]] = {}
-    for text in texts:
+    for text in map(joined, texts):
         m = _MODULE.search(text)
         if m is None:
             raise ValueError("HLO text without an 'HloModule' line")
@@ -187,6 +203,14 @@ def _profile(path: str):
     return ops, runs, spans
 
 
+def labels(table: dict, module: str, instr: str) -> Tuple[str, str]:
+    """(kind, scope) of one instruction by :func:`op_table`'s ``table``:
+    ``unscoped`` where its op_name has no ``repro.`` component,
+    ``unmapped`` where its program's text was not given."""
+    kind, scope = table.get(module, {}).get(instr, ("", None))
+    return kind, UNMAPPED if scope is None else scope or UNSCOPED
+
+
 def scoped(plain: tr.Trace, path: str, texts: Iterable[str]) -> Scoped:
     """``plain``, the trace ``bench.trace.load`` made of ``path``, with
     each operation's kind and scope, each device's module runs and the
@@ -205,12 +229,9 @@ def scoped(plain: tr.Trace, path: str, texts: Iterable[str]) -> Scoped:
             raise ValueError(f"{path}: bench.trace.load moved {dev}'s "
                              "operations by more than one shift")
         shift = shifts.pop() if shifts else 0
-        out = []
-        for (name, lo, hi), (_, _, _, module, instr) in zip(mine, raw):
-            kind, scope = table.get(module, {}).get(instr, ("", None))
-            out.append((name, lo, hi, kind,
-                        UNMAPPED if scope is None else scope or UNSCOPED))
-        devices[dev] = out
+        devices[dev] = [(name, lo, hi) + labels(table, module, instr)
+                        for (name, lo, hi), (_, _, _, module, instr)
+                        in zip(mine, raw)]
         modules[dev] = [(_RUN_ID.sub("", n), lo + shift, hi + shift)
                         for n, lo, hi in runs.get(dev, [])]
     return Scoped(devices, modules,
@@ -276,6 +297,20 @@ def summarize(t: Scoped, top: int = 10) -> dict:
             "host_spans": self_times(t.spans, lo, hi),
             "idle_gaps": [[label(t.spans, a, b), (b - a) * 1e-9]
                           for a, b in longest]}
+
+
+def layer_summary(t: Scoped) -> dict:
+    """What the harness adds to ``bench.trace.summarize``'s summary for
+    the per-layer readers: ``scopes``, device seconds by scope (``unscoped``
+    for operations under none), mean over chips; ``host_spans``, self
+    seconds of each ``repro.`` span; ``unmapped``, device seconds of
+    operations whose program's text was not given, or was given twice
+    with different op_names (mean over chips)."""
+    s = summarize(t)
+    scopes = dict(s["scopes"])
+    unmapped = scopes.pop(UNMAPPED, 0.0)
+    return {"scopes": scopes, "host_spans": s["host_spans"],
+            "unmapped": unmapped}
 
 
 def dispatch_to_fetch(spans: Sequence[Event]) -> List[tr.Interval]:

@@ -1,5 +1,8 @@
 """CPU rehearsal helpers: the cells at a tiny size, and the harness with
 its look for a chip steered to the CPU."""
+import json
+import os
+
 import jax
 import pytest
 
@@ -7,38 +10,40 @@ from bench import peaks
 from bench import run as R
 from bench import trace as tr
 
-# the smoke widths of the LM, a few hundred SVM rows
-TINY_CONFIG = {
-    "smollm-360m": {"config": dict(
-        hidden_size=96, intermediate_size=256, num_hidden_layers=2,
-        num_attention_heads=3, num_key_value_heads=1, head_dim=32,
-        vocab_size=512)},
-    "svm-epsilon": {"samples": 500, "features": 22},
-}
-TINY_TRAFFIC = {
-    "smollm-360m.s4096": {"seq_len": 64},
-    "svm-epsilon.k4.b64": {"epochs_per_job": 2, "block_per_worker": 8},
-}
-# the SVM cell's limit is set from chip readings at the cell's own size;
-# at the test size it is set from CPU readings: program w_rel_l2 0 on one
-# device and at most 7.6e-8 on four, control 2.4e-6 to 2.7e-6, faults
-# 0.17 and more
-TINY_CHECK = {
-    "svm-epsilon.k4.b64": {"w_rel_l2": 5e-7},
-}
+# one file of tiny sizes per configuration and per cell, by its name
+TINY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "tiny")
+
+
+def tiny(name: str) -> dict:
+    """``tiny/<name>.json``: the keys of a configuration's or a cell's
+    file that change at the test size (a group of keys merged into the
+    file's own group); ``note`` says why."""
+    path = os.path.join(TINY, name + ".json")
+    if not os.path.exists(path):
+        raise FileNotFoundError(f"no tiny sizes for {name!r}: add {path}")
+    with open(path) as f:
+        data = json.load(f)
+    data.pop("note", None)
+    return data
 
 
 def shrink(path_parts, data):
     """The tiny version of a configuration or cell file."""
     kind, name = path_parts[-2], path_parts[-1][:-len(".json")]
-    if kind == "configs":
-        for k, v in TINY_CONFIG[name].items():
-            data[k] = {**data[k], **v} if isinstance(v, dict) else v
+    for k, v in tiny(name).items():
+        data[k] = {**data[k], **v} if isinstance(v, dict) else v
     if kind == "workloads":
-        data["traffic_params"].update(TINY_TRAFFIC[name])
-        data["check"] = {**data["check"], **TINY_CHECK.get(name, {})}
         data["chips"] = 1                  # one CPU device here
     return data
+
+
+def cells_driven_by(driver: str, chips=(1, 4)) -> list:
+    """The cells of ``BENCHMARK.json`` whose configuration ``driver``
+    runs, on the given numbers of chips."""
+    spec = R.load_json(R.ROOT, "BENCHMARK.json")
+    return [w["name"] for w in spec["workloads"] if w["chips"] in chips
+            and R.load_json(R.BENCH, "configs", w["config"] + ".json")[
+                "driver"] == driver]
 
 
 def cpu_trace(path):

@@ -45,11 +45,12 @@ def test_svm_control_reads_above_program(seed, monkeypatch):
     # one worker here: the exchange fault is test_faults' to catch
     monkeypatch.setattr(faults, "SVM", tuple(
         f for f in faults.SVM if f != "no_exchange"))
-    ctx = tiny_ctx("svm-epsilon.k4.b64", seed)
-    got = D.control_readings(ctx)
-    limits = ctx.cell["check"]
-    assert not fails(got["program"], limits), got["program"]
-    assert fails(got["control"], limits), got["control"]
-    assert got["control"]["w_rel_l2"] > 10 * got["program"]["w_rel_l2"]
-    for f in faults.SVM:
-        assert fails(got[f], limits), (f, got[f])
+    for cell in T.cells_driven_by("svm_dms"):
+        ctx = tiny_ctx(cell, seed)
+        got = D.control_readings(ctx)
+        limits = ctx.cell["check"]
+        assert not fails(got["program"], limits), (cell, got["program"])
+        assert fails(got["control"], limits), (cell, got["control"])
+        assert got["control"]["w_rel_l2"] > 10 * got["program"]["w_rel_l2"]
+        for f in faults.SVM:
+            assert fails(got[f], limits), (cell, f, got[f])

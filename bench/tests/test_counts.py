@@ -44,3 +44,20 @@ def test_peaks_known_kind():
 def test_peaks_unknown_kind_raises():
     with pytest.raises(KeyError, match="no peaks"):
         peaks.lookup("TPU v9 imaginary")
+
+
+@pytest.mark.parametrize("n_local,block,epochs,overlap,topology", [
+    (80_000, 64, 12, "none", "all"), (320_000, 64, 12, "none", "all"),
+    (1000, 7, 3, "none", "ring"), (1000, 8, 2, "delayed", "all")])
+def test_svm_job_counts_match_the_programs(n_local, block, epochs, overlap,
+                                           topology):
+    from repro.core import svm
+    got = counts.svm_job_counts(n_local, block, epochs, overlap, topology)
+    want = svm.dms_job_counts(n_local, 2000, block, epochs, overlap=overlap,
+                              topology=topology)
+    assert got == {k: want[k] for k in ("blocks", "syncs")}
+
+
+def test_svm_job_counts_of_the_cells():
+    assert counts.svm_job_counts(80_000, 64, 12)["syncs"] == 15_000
+    assert counts.svm_job_counts(320_000, 64, 12)["blocks"] == 60_000

@@ -2,7 +2,8 @@
 once for each fault a cell can have (``bench.faults``). The harness's
 look for a chip is steered here; the rest of the run is the harness's.
 The four-worker SVM cell runs on four virtual CPU devices in a child
-process, so that the exchange between workers exists."""
+process, so that the exchange between workers exists; a one-worker cell
+has no exchange to leave out."""
 import json
 import os
 import subprocess
@@ -11,6 +12,7 @@ import sys
 import pytest
 
 from bench import faults
+from bench.tests.conftest import cells_driven_by
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -21,6 +23,16 @@ def test_lm_fault_is_not_correct(harness, fault):
     with faults.lm(fault):
         out = harness.run_cell("smollm-360m.s4096", 2 ** 31 + 3, 0.5,
                                False)
+    assert out["correct"] is False, (fault, out["check"])
+
+
+@pytest.mark.parametrize("cell", cells_driven_by("svm_dms", chips=(1,)))
+@pytest.mark.parametrize("fault", [f for f in faults.SVM
+                                   if f != "no_exchange"])
+def test_svm_one_chip_fault_is_not_correct(harness, cell, fault):
+    """A one-worker cell exchanges nothing: every other fault."""
+    with faults.svm(fault):
+        out = harness.run_cell(cell, 2 ** 31 + 9, 0.5, False)
     assert out["correct"] is False, (fault, out["check"])
 
 

@@ -1,7 +1,9 @@
-"""Each cell run end to end on the CPU at a tiny size, with the harness's
-look for a chip steered here (``conftest.harness``): every file is found
-by name, the timed path runs, the check passes, and the last line has the
-contract's keys."""
+"""Each cell of ``BENCHMARK.json`` run end to end on the CPU at its tiny
+size (``bench/tests/tiny/``), with the harness's look for a chip steered
+here (``conftest.harness``): every file is found by name, the timed path
+runs, the check passes, the last line has the contract's keys, and each
+per-layer reader reads what its cells' traced runs give it."""
+import dataclasses
 import io
 import json
 import re
@@ -11,12 +13,19 @@ import jax
 import pytest
 
 from bench import run as R
+from bench.tests import conftest as T
 
 KEYS = {"correct", "attempted", "failed", "metrics", "device", "check"}
 
 
 def spec():
     return R.load_json(R.ROOT, "BENCHMARK.json")
+
+
+CELLS = [w["name"] for w in spec()["workloads"]]
+# each per-layer metric with each cell that reports it
+READS = [(m["name"], cell) for cell in CELLS
+         for m in R.cell_metrics(spec(), cell)[1]]
 
 
 def test_every_entry_has_its_files():
@@ -32,6 +41,9 @@ def test_every_entry_has_its_files():
         for k in ("config", "traffic", "chips", "why"):
             assert cell[k] == w[k], (w["name"], k)
         assert cell["check"]
+        T.tiny(w["name"])
+    for c in s["configs"]:
+        T.tiny(c["name"])
     for m in s["per_layer"]:
         assert callable(R.load_module("metrics", m["name"]).read)
 
@@ -76,15 +88,39 @@ def test_no_tpu_exits_nonzero():
     assert e.value.code not in (0, None)
 
 
-@pytest.mark.parametrize("cell", ["smollm-360m.s4096", "svm-epsilon.k4.b64"])
-@pytest.mark.parametrize("trace", [0, 1])
-def test_cell_end_to_end(harness, cell, trace):
+# the traced run of each cell, and what its per-layer readers were given
+TRACED = {}
+
+
+def run_traced(monkeypatch, cell):
+    """The cell's last line with ``--trace 1`` and the ``Reading`` its
+    readers got, run once a module."""
+    if cell not in TRACED:
+        kept, real = [], R.Reading
+
+        class Kept(real):
+            def __init__(self, *args):
+                super().__init__(*args)
+                kept.append(real(*args))
+        with monkeypatch.context() as m:
+            m.setattr(R, "Reading", Kept)
+            TRACED[cell] = (run(cell, 1), kept[-1])
+    return TRACED[cell]
+
+
+def run(cell, trace):
     out = io.StringIO()
     with redirect_stdout(out):
         rc = R.main(["--workload", cell, "--seed", str(2 ** 31 + 17),
                      "--seconds", "1", "--trace", str(trace)])
     assert rc == 0
-    last = json.loads(out.getvalue().strip().splitlines()[-1])
+    return json.loads(out.getvalue().strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("trace", [0, 1])
+def test_cell_end_to_end(harness, monkeypatch, cell, trace):
+    last = run_traced(monkeypatch, cell)[0] if trace else run(cell, 0)
     assert set(last) == KEYS | ({"breakdown"} if trace else set())
     assert list(last)[-1] == "check"
     assert last["correct"] is True, last
@@ -114,27 +150,21 @@ def test_seed_makes_the_inputs():
     assert not (jax.random.key_data(k1) == jax.random.key_data(k2)).all()
 
 
-def test_every_metric_reader_reads_a_summary():
-    """Each reader in ``bench/metrics`` turns a trace summary and a run's
-    counts into a number, or into nothing where it has nothing to read."""
-    import glob
-    import os
-    from bench import peaks
-    from bench.tests.test_trace import synthetic
-    from bench import trace as tr
-    summary = tr.summarize(synthetic())
-    cfg = {"features": 2000, "config": R.load_json(
-        R.BENCH, "configs", "smollm-360m.json")["config"]}
-    cell = {"traffic_params": {"seq_len": 4096}}
-    counts = {"traced_samples": 1000, "traced_tokens": 10,
-              "traced_steps": 1}
-    for path in sorted(glob.glob(os.path.join(R.BENCH, "metrics", "*.py"))):
-        name = os.path.basename(path)[:-3]
-        read = R.load_module("metrics", name).read
-        r = R.Reading(summary, counts, peaks.lookup("TPU v5 lite"), 2, cfg,
-                      cell)
-        value = read(r)
-        assert isinstance(value, float) and value >= 0, (name, value)
-        empty = R.Reading(None, {}, peaks.lookup("TPU v5 lite"), 2, cfg,
-                          cell)
-        assert read(empty) is None, name
+@pytest.mark.parametrize("metric,cell", READS)
+def test_every_metric_reader_reads_a_summary(harness, monkeypatch, metric,
+                                             cell):
+    """Each reader in ``bench/metrics``, given what a traced run of a cell
+    it is listed for hands it (the cell's configuration, tiny traffic and
+    counts, a summary with the program's scopes), reads a number; it
+    reads nothing, and never 0, where its input is absent."""
+    _, r = run_traced(monkeypatch, cell)
+    assert {"scopes", "host_spans", "unmapped"} <= set(r.summary)
+    assert "unmapped" not in r.summary["scopes"]
+    read = R.load_module("metrics", metric).read
+    value = read(r)
+    assert isinstance(value, float) and value >= 0, (metric, value)
+    assert read(dataclasses.replace(r, summary=None)) is None
+    assert read(dataclasses.replace(r, counts={})) in (None, value)
+    bare = {k: v for k, v in r.summary.items()
+            if k not in ("scopes", "host_spans", "unmapped")}
+    assert read(dataclasses.replace(r, summary=bare)) in (None, value)

@@ -1,6 +1,7 @@
-"""``bench/scope_joined.py``: a Mosaic kernel's multi-line attribute block
-joined onto its instruction's line, so that its scope is read."""
-from bench import scope_joined as sj, scopes as sc
+"""A Mosaic kernel's multi-line attribute block joined onto its
+instruction's line by ``bench.scopes.op_table``, so that its scope is
+read; and ``bench/scope_report.py --gaps``."""
+from bench import scope_report as sr, scopes as sc
 
 KERNEL = """HloModule jit_step, is_scheduled=true
 
@@ -15,12 +16,14 @@ ENTRY %main (q: bf16[2,512,64]) -> bf16[2,512,64] {
 
 
 def test_a_kernel_reads_its_scope_once_joined():
-    plain = sc.op_table([KERNEL])["jit_step"]
-    assert plain["splash_mqa_fwd.1"] == ("custom-call", "")
-    table = sc.op_table([sj.joined(KERNEL)])["jit_step"]
+    # one line an instruction: the kernel's op_name sits three lines below
+    line = next(x for x in KERNEL.splitlines() if "splash_mqa_fwd.1 =" in x)
+    assert "op_name" not in line
+    table = sc.op_table([KERNEL])["jit_step"]
     assert table["splash_mqa_fwd.1"] == ("custom-call", "lm.attention")
     assert table["add.2"] == ("add", "lm.mlp")
-    assert sj.joined(sj.joined(KERNEL)) == sj.joined(KERNEL)
+    assert sc.op_table([sc.joined(KERNEL)]) == sc.op_table([KERNEL])
+    assert sc.joined(sc.joined(KERNEL)) == sc.joined(KERNEL)
 
 
 def test_gaps_longest_first_with_their_module_run():
@@ -32,7 +35,7 @@ def test_gaps_longest_first_with_their_module_run():
                   {"/device:TPU:0": [("jit_step", 0, 3_000_000),
                                      ("jit_loss", 3_100_000, 3_400_000)]},
                   [])
-    got = sj.gaps(t)
+    got = sr.gaps(t)
     assert [(g["after"], g["before"], g["ms"]) for g in got] == [
         ("b", "c", 0.5), ("c", "d", 0.2)]
     assert got[0]["in_module_run"] == ["jit_step"]

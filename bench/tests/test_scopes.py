@@ -245,13 +245,126 @@ def test_cell_traced_by_scope_on_the_cpu(harness, cell, tmp_path):
         # every executed exchange is the block's
         assert s["collectives"] == {
             "svm.sync": r["units"] * r["per_unit"]["syncs"]}
-        assert r["readings"]["svm_sync_us"] > 0
+        assert r["result"]["metrics"]["svm_sync_us"]["value"] > 0
     else:
         for name in ("lm.attention", "lm.mlp", "lm.loss", "lm.optimizer"):
             assert s["scopes"][name] > 0, name
         for name in ("step", "data", "dispatch", "fetch"):
             assert s["host_spans"][name] > 0, name
-        assert r["readings"]["dispatch_ms"] > 0
+        assert r["result"]["metrics"]["dispatch_ms.lm"]["value"] > 0
     with open(rec) as f:
         t = sc.Scoped.from_json(f.read())
     assert sc.summarize(t)["devices"] == 1
+
+
+# the recorded scan's one program, with a scope on two of its
+# instructions; copy-start and copy-done are left out of the text
+SCAN_HLO = """HloModule jit_scan, is_scheduled=true
+
+ENTRY %main (xs.1: f32[8,256,256]) -> f32[256,256] {
+  %convert.1 = bf16[8,256,256]{2,1,0} convert(%xs.1)
+  %copy.11 = f32[256,256]{1,0} copy(%get-tuple-element.36), metadata={op_name="jit(scan)/while/body/repro.COPY/copy"}
+  ROOT %fusion.16 = f32[256,256]{1,0} fusion(%copy.11, %get-tuple-element.41), kind=kOutput, calls=%fused_computation.3, metadata={op_name="jit(scan)/while/body/repro.FUSION/dot_general"}
+}
+"""
+# each instruction's device ns in the recorded traced stretch
+SCAN_NS = {"fusion.16": 27970, "copy.11": 722, "convert.1": 6691,
+           "copy-start": 11, "copy-start.1": 6, "copy-done": 1151,
+           "copy-done.1": 276}
+
+
+def recorded_scan(fusion_scope: str, copy_scope: str) -> sc.Scoped:
+    """The recorded scan by scope, each ``bench.job`` a ``repro.step``
+    whose first quarter is its ``repro.dispatch``."""
+    with open(os.path.join(DATA, "scan_1chip.trace.json")) as f:
+        t = tr.Trace.from_json(f.read())
+    table = sc.op_table([SCAN_HLO.replace("COPY", copy_scope)
+                         .replace("FUSION", fusion_scope)])
+    ops = {d: [(n, a, b) + sc.labels(table, *sc.resolve(n, {}, "jit_scan(3)"))
+               for n, a, b in ev] for d, ev in t.devices.items()}
+    steps = [(a, b) for n, a, b in t.spans if n == "bench.job"]
+    spans = t.spans + [s for a, b in steps for s in (
+        ("repro.step", a, b), ("repro.dispatch", a, a + (b - a) // 4))]
+    return sc.Scoped(ops, {}, sorted(spans, key=lambda s: s[1]))
+
+
+def test_layer_summary_of_the_recorded_scan():
+    """The scope keys on a trace recorded on the chip: seconds by scope
+    and unmapped add up to the busy time, and the summary's other keys
+    are ``bench/trace.py``'s, unchanged."""
+    t = recorded_scan("svm.block", "svm.sync")
+    s = tr.summarize(t.plain())
+    with open(os.path.join(DATA, "scan_1chip.summary.json")) as f:
+        assert json.dumps(s, sort_keys=True) == f.read().strip()
+    keys = sc.layer_summary(t)
+    assert keys["scopes"] == pytest.approx({
+        "svm.block": SCAN_NS["fusion.16"] * 1e-9,
+        "svm.sync": SCAN_NS["copy.11"] * 1e-9,
+        "unscoped": SCAN_NS["convert.1"] * 1e-9})
+    assert keys["unmapped"] == pytest.approx(sum(
+        SCAN_NS[k] for k in SCAN_NS if k.startswith("copy-")) * 1e-9)
+    assert sum(keys["scopes"].values()) + keys["unmapped"] == \
+        pytest.approx(s["mean"]["busy_s"])
+    jobs = [b - a for n, a, b in t.spans if n == "bench.job"]
+    assert keys["host_spans"]["dispatch"] == pytest.approx(
+        sum(j // 4 for j in jobs) * 1e-9)
+    assert keys["host_spans"]["step"] == pytest.approx(
+        sum(j - j // 4 for j in jobs) * 1e-9)
+
+
+# a reading of the recorded scan: two units, each of 8 blocks and syncs
+SCAN_COUNTS = {"traced_samples": 2048, "traced_jobs": 2, "traced_blocks": 16,
+               "traced_syncs": 16, "traced_steps": 2, "traced_tokens": 2}
+
+
+def scan_reading(metric: str, t: sc.Scoped):
+    from bench import peaks, run as R
+    lm = metric.endswith(".lm")
+    config = R.load_json(R.BENCH, "configs",
+                         "smollm-360m.json" if lm else "svm-epsilon.json")
+    cell = R.load_json(R.BENCH, "workloads", "smollm-360m.s4096.json" if lm
+                       else "svm-epsilon.k4.b64.json")
+    summary = tr.summarize(t.plain())
+    summary.update(sc.layer_summary(t))
+    return R.load_module("metrics", metric).read, R.Reading(
+        summary, SCAN_COUNTS, peaks.lookup("TPU v5 lite"), 1, config, cell)
+
+
+@pytest.mark.parametrize("metric,fusion,copy,want", [
+    ("svm_block_us", "svm.block", "svm.sync", 1e6 * 27970e-9 / 16),
+    ("svm_sync_us", "svm.block", "svm.sync", 1e6 * 722e-9 / 16),
+    ("attn_ms.lm", "lm.attention", "lm.optimizer", 1e3 * 27970e-9 / 2),
+    ("opt_ms.lm", "lm.attention", "lm.optimizer", 1e3 * 722e-9 / 2),
+    ("dispatch_ms.lm", "lm.attention", "lm.optimizer", None),
+])
+def test_scope_readers_on_the_recorded_scan(metric, fusion, copy, want):
+    """Each reader of a scope or span on the recorded scan; with the
+    scope or span absent, it reads nothing, not 0."""
+    t = recorded_scan(fusion, copy)
+    if want is None:
+        jobs = [b - a for n, a, b in t.spans if n == "bench.job"]
+        want = 1e3 * sum(j // 4 for j in jobs) * 1e-9 / 2
+    read, r = scan_reading(metric, t)
+    assert read(r) == pytest.approx(want)
+    read, r = scan_reading(metric, recorded_scan("lm.mlp", "lm.loss"))
+    r.summary["host_spans"].pop("dispatch")
+    assert read(r) is None
+
+
+# what the readers of bench/trace.py's summary read on the recorded scan
+# with SCAN_COUNTS, before scopes were added to the summary
+EXISTING = {
+    "host_gap_ms.lm": 0.6788265000000001,
+    "idle_share.lm": 97.35908725833286,
+    "idle_share.svm": 97.35908725833286,
+    "mfu.lm": 80.64947492866563,
+    "mfu.svm": 1.4352939050317286,
+    "svm_block_roofline": 54.3484032011471,
+    "sync_exposed_share.svm": 0.0,
+}
+
+
+@pytest.mark.parametrize("metric", sorted(EXISTING))
+def test_existing_readers_unchanged_on_the_recorded_scan(metric):
+    read, r = scan_reading(metric, recorded_scan("svm.block", "svm.sync"))
+    assert read(r) == EXISTING[metric]
