@@ -37,7 +37,8 @@ class ModelConfig:
     """Unified architecture description covering every assigned family."""
 
     name: str = "unnamed"
-    family: str = "dense"          # dense | moe | ssm | hybrid | audio | vlm
+    # dense | moe | ssm | hybrid | interleaved | audio | vlm
+    family: str = "dense"
     n_layers: int = 2
     d_model: int = 128
     n_heads: int = 4
@@ -59,6 +60,20 @@ class ModelConfig:
     # hybrid (zamba2-style): a shared attention+MLP block applied every
     # `shared_block_every` backbone layers.
     shared_block_every: int = 0
+    # interleaved hybrid (granite-4.0-h-style): each layer's mixer,
+    # "mamba" or "attention", in order; every layer has its own MLP
+    layer_types: Tuple[str, ...] = ()
+    # "rope" | "nope" (self-attention without a positional embedding)
+    position_embedding: str = "rope"
+    # softmax scale of the attention scores; 0 => 1/sqrt(head_dim)
+    attention_multiplier: float = 0.0
+    # µP-style multipliers: token embeddings are multiplied by
+    # `embedding_multiplier`, each sublayer's output by
+    # `residual_multiplier` before its residual add, and the logits are
+    # divided by `logits_scaling`; at 1 the operation is left out
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # enc-dec (whisper-style)
     n_encoder_layers: int = 0
     # stubbed audio frontend: number of precomputed frame embeddings the

@@ -1,7 +1,8 @@
-"""Assigned architecture configs. Importing this package registers all ten
-``--arch`` ids (plus the paper's SVM dataset configs in ``svm_datasets``)."""
+"""Assigned architecture configs. Importing this package registers all
+eleven ``--arch`` ids (plus the paper's SVM dataset configs in ``svm_datasets``)."""
 
 from repro.configs import (  # noqa: F401
+    granite4_h_micro,
     internlm2_1p8b,
     llama32_3b,
     mamba2_2p7b,

@@ -15,6 +15,8 @@ Names. Device scopes and host spans take their names from ``SCOPES`` and
   TPU kernel; ``chunked`` and ``full``, the jnp paths; ``pallas``, the
   forward-only kernel a caller selects). A layer scan traces its body
   once; the scan counts it once per layer (:meth:`PathCounts.repeated`).
+  :data:`SSD` counts the Mamba-2 mixer's SSD calls the same way, by path
+  (``chunked``, the jnp chunked scan of ``models/ssm.py``).
 * :func:`span` and :func:`step_span` are ``jax.profiler`` annotations on
   the profiler's host clock, which cost next to nothing while no trace is
   being taken. :class:`repro.runtime.ft.StepRunner` wraps each step in
@@ -51,9 +53,10 @@ import jax
 PREFIX = "repro."
 # device scopes: the SVM's local block update and its model exchange; the
 # LM's attention (projections through ``wo``), MLP, cross-entropy,
-# optimizer update and replica sync
+# optimizer update and replica sync; the Mamba-2 mixer (projections, conv,
+# gated norm, out-projection) and, nested in it, its chunked SSD scan
 SCOPES = ("svm.block", "svm.sync", "lm.attention", "lm.mlp", "lm.loss",
-          "lm.optimizer", "lm.sync")
+          "lm.optimizer", "lm.sync", "lm.ssm", "lm.ssd")
 # host spans of the step loop: ``step`` encloses one step's others
 SPANS = ("step", "data", "dispatch", "fetch", "save", "ladder", "restore")
 
@@ -116,6 +119,8 @@ class PathCounts:
 
 # attention calls by path (``models/attention.py::full_attention``)
 ATTENTION = PathCounts(("flash", "chunked", "full", "pallas"))
+# SSD calls by path (``models/ssm.py::mamba_fwd``)
+SSD = PathCounts(("chunked",))
 
 
 class EMA:

@@ -84,17 +84,24 @@ def make_mask(q_len: int, kv_len: int, mode: str, prefix_len: int = 0,
     raise ValueError(mode)
 
 
-def _sdpa_jnp(q, k, v, mask) -> jax.Array:
+def _scaled(scores: jax.Array, hd: int, scale: Optional[float]):
+    """Scores times the softmax scale ``scale``, or divided by sqrt(hd)
+    where it is None."""
+    return scores / (hd ** 0.5) if scale is None else scores * scale
+
+
+def _sdpa_jnp(q, k, v, mask, scale: Optional[float] = None) -> jax.Array:
     """Grouped-query scaled-dot-product attention, jnp reference.
 
-    q: (B,S,H,hd) · k/v: (B,T,KV,hd) → (B,S,H,hd). H = KV·G.
+    q: (B,S,H,hd) · k/v: (B,T,KV,hd) → (B,S,H,hd). H = KV·G. ``scale``
+    multiplies the scores (None: 1/sqrt(hd)).
     """
     b, s, h, hd = q.shape
     kv = k.shape[2]
     g = h // kv
     q = q.reshape(b, s, kv, g, hd)
     scores = jnp.einsum("bskgd,btkd->bkgst", q, k).astype(jnp.float32)
-    scores = scores / (hd ** 0.5)
+    scores = _scaled(scores, hd, scale)
     if mask is not None:
         scores = jnp.where(mask[None, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
@@ -111,7 +118,8 @@ _Q_CHUNK = 512
 
 
 def _sdpa_chunked_jnp(q, k, v, mask_mode: str, prefix_len: int,
-                      q_chunk: int = _Q_CHUNK) -> jax.Array:
+                      q_chunk: int = _Q_CHUNK,
+                      scale: Optional[float] = None) -> jax.Array:
     """Query-chunked SDPA: lax.scan over q blocks, full softmax row per
     block (f32). Scores live at (B,H,q_chunk,T) per step — O(S) not O(S²)
     memory. XLA-lowerable twin of the Pallas flash kernel.
@@ -137,7 +145,8 @@ def _sdpa_chunked_jnp(q, k, v, mask_mode: str, prefix_len: int,
     kv = k.shape[2]
     g = h // kv
     if s % q_chunk != 0:
-        return _sdpa_jnp(q, k, v, make_mask(s, t, mask_mode, prefix_len))
+        return _sdpa_jnp(q, k, v, make_mask(s, t, mask_mode, prefix_len),
+                         scale)
     nq = s // q_chunk
     cols = jnp.arange(t)[None, :]
 
@@ -178,8 +187,8 @@ def _sdpa_chunked_jnp(q, k, v, mask_mode: str, prefix_len: int,
         def block_flat(carry, inp):
             qc, iq = inp                                 # (B,Qc,H,hd)
             qc = constrain(qc, "batch", "attn_q_seq", "heads", "head_dim")
-            scores = jnp.einsum("bshd,bthd->bhst", qc,
-                                kr).astype(jnp.float32) / (hd ** 0.5)
+            scores = _scaled(jnp.einsum("bshd,bthd->bhst", qc,
+                                        kr).astype(jnp.float32), hd, scale)
             scores = _mask(scores, iq, 2)
             scores = constrain(scores, "batch", "heads", "attn_q_seq", None)
             probs = jax.nn.softmax(scores, axis=-1).astype(qc.dtype)
@@ -201,7 +210,7 @@ def _sdpa_chunked_jnp(q, k, v, mask_mode: str, prefix_len: int,
         qc = constrain(qc, "batch", q_axis, "kv_heads", "q_group",
                        "head_dim")
         scores = jnp.einsum("bskgd,btkd->bkgst", qc, k).astype(jnp.float32)
-        scores = scores / (hd ** 0.5)
+        scores = _scaled(scores, hd, scale)
         scores = _mask(scores, iq, 3)
         scores = constrain(scores, "batch", "kv_heads", "q_group",
                            q_axis, None)
@@ -289,10 +298,13 @@ def _flash_spec(b: int, h: int, kv: int) -> Optional[P]:
     return spec
 
 
-def _sdpa_flash(q, k, v, interpret: Optional[bool] = None) -> jax.Array:
+def _sdpa_flash(q, k, v, interpret: Optional[bool] = None,
+                scale: Optional[float] = None) -> jax.Array:
     """Causal GQA attention through the fused splash kernel.
 
-    q: (B,S,H,hd) · k/v: (B,S,KV,hd) → (B,S,H,hd). One vmap over the
+    q: (B,S,H,hd) · k/v: (B,S,KV,hd) → (B,S,H,hd). The kernel does not
+    scale its scores: q is multiplied by ``scale`` (None: 1/sqrt(hd))
+    first, in its own dtype. One vmap over the
     B·KV (batch, KV head) pairs, each call attending G = H/KV query heads
     to one shared KV head (never repeated); a vmap over batch and another
     over KV heads left ≈ 10 ms more copy waits in each step on a v5e
@@ -309,7 +321,7 @@ def _sdpa_flash(q, k, v, interpret: Optional[bool] = None) -> jax.Array:
     mask, sizes = _splash_plan(s, g)
     kernel = splash.make_splash_mqa_single_device(
         mask, block_sizes=sizes, interpret=interpret)
-    q = q * jnp.asarray(hd ** -0.5, q.dtype)       # splash does not scale
+    q = q * jnp.asarray(hd ** -0.5 if scale is None else scale, q.dtype)
 
     def attend(q, k, v):
         bl, kvl = q.shape[0], k.shape[2]
@@ -344,11 +356,15 @@ def full_attention(params, x: jax.Array, positions: jax.Array, cfg: ModelConfig,
     """Training / prefill attention over a full sequence (optionally cross).
 
     ``return_kv=True`` also returns the (post-RoPE) k, v — the prefill path
-    stores them directly as the decode cache.
+    stores them directly as the decode cache. The config's
+    ``position_embedding`` ("nope": no RoPE) and ``attention_multiplier``
+    (the softmax scale, 1/sqrt(hd) at 0) hold on every path.
     """
+    scale = cfg.attention_multiplier or None
     with scope("lm.attention"):
         q, k, v = _project_qkv(params, x, kv_x, cfg)
-        use_rope = kv_x is None  # no RoPE across enc-dec cross attention
+        # no RoPE across enc-dec cross attention
+        use_rope = kv_x is None and cfg.position_embedding == "rope"
         if use_rope:
             cos, sin = rotary_cos_sin(positions, cfg)
             q = L.apply_rope(q, cos, sin)
@@ -357,17 +373,21 @@ def full_attention(params, x: jax.Array, positions: jax.Array, cfg: ModelConfig,
             q.shape, k.shape[2], mask_mode, cross=kv_x is not None))
         ATTENTION.add(path)
         if path == "pallas":
+            if scale is not None:
+                raise ValueError("the forward-only Pallas kernel scales "
+                                 "by 1/sqrt(head_dim) only")
             from repro.kernels.flash_attention import ops as fa_ops
             out = fa_ops.flash_attention(
                 q, k, v, causal=(mask_mode == "causal"),
                 prefix_len=prefix_len if mask_mode == "prefix" else 0)
         elif path == "flash":
-            out = _sdpa_flash(q, k, v)
+            out = _sdpa_flash(q, k, v, scale=scale)
         elif path == "chunked":
-            out = _sdpa_chunked_jnp(q, k, v, mask_mode, prefix_len)
+            out = _sdpa_chunked_jnp(q, k, v, mask_mode, prefix_len,
+                                    scale=scale)
         else:
             mask = make_mask(q.shape[1], k.shape[1], mask_mode, prefix_len)
-            out = _sdpa_jnp(q, k, v, mask)
+            out = _sdpa_jnp(q, k, v, mask, scale)
         out = constrain(out, "batch", "seq", "heads", "head_dim")
         y = jnp.einsum("bshd,hdm->bsm", out, params["wo"].astype(x.dtype))
         y = constrain(y, "batch", "act_seq", "embed")
@@ -395,7 +415,8 @@ def cache_logical_axes() -> Tuple[str, ...]:
     return ("layers", "batch", "cache_seq", "kv_heads", "head_dim")
 
 
-def _decode_attn_chunk(q, k_chunk, v_chunk, index, chunk_offset):
+def _decode_attn_chunk(q, k_chunk, v_chunk, index, chunk_offset,
+                       scale: Optional[float] = None):
     """Per-shard flash-decode partial: returns (o, l, m) to be lse-merged.
 
     q: (B,1,KV,G,hd) · k/v_chunk: (B,Sc,KV,hd); positions chunk_offset+i
@@ -403,7 +424,7 @@ def _decode_attn_chunk(q, k_chunk, v_chunk, index, chunk_offset):
     """
     sc = k_chunk.shape[1]
     scores = jnp.einsum("bqkgd,btkd->bkgqt", q, k_chunk).astype(jnp.float32)
-    scores = scores / (q.shape[-1] ** 0.5)
+    scores = _scaled(scores, q.shape[-1], scale)
     pos = chunk_offset + jnp.arange(sc)
     valid = pos <= index
     scores = jnp.where(valid[None, None, None, None, :], scores, -jnp.inf)
@@ -417,8 +438,8 @@ def _decode_attn_chunk(q, k_chunk, v_chunk, index, chunk_offset):
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     index: jax.Array, mesh=None, seq_shard_axis: str = "model"
-                     ) -> jax.Array:
+                     index: jax.Array, mesh=None, seq_shard_axis: str = "model",
+                     scale: Optional[float] = None) -> jax.Array:
     """One-token attention against a sequence-sharded cache.
 
     q: (B,1,H,hd); k/v_cache: (B,S,KV,hd) sharded (data, model, -, -).
@@ -438,7 +459,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     if mesh is None or n_shards <= 1 or s_total % n_shards != 0:
         # single-shard math (no mesh, or a cache length that doesn't tile
         # the model axis, e.g. whisper's 1500-frame cross cache)
-        o, l, m, has = _decode_attn_chunk(qg, k_cache, v_cache, index, 0)
+        o, l, m, has = _decode_attn_chunk(qg, k_cache, v_cache, index, 0,
+                                          scale)
         out = (o / jnp.maximum(l, 1e-30)).astype(q.dtype)
         return out.reshape(b, 1, h, hd)
 
@@ -447,13 +469,14 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     def shard_fn(qg, k_chunk, v_chunk, index):
         shard_id = jax.lax.axis_index(seq_shard_axis)
         o, l, m, _ = _decode_attn_chunk(qg, k_chunk, v_chunk, index,
-                                        shard_id * chunk)
+                                        shard_id * chunk, scale)
         # lse merge across shards — all-reduce payloads kept f32 (XLA's
         # bf16 AllReducePromotion pass CHECK-crashes on these ARs)
         m_glob = jax.lax.pmax(m, seq_shard_axis)
-        scale = jnp.exp(m - m_glob)
-        l_glob = jax.lax.psum(l * scale, seq_shard_axis)
-        o_glob = jax.lax.psum(o.astype(jnp.float32) * scale, seq_shard_axis)
+        rescale = jnp.exp(m - m_glob)
+        l_glob = jax.lax.psum(l * rescale, seq_shard_axis)
+        o_glob = jax.lax.psum(o.astype(jnp.float32) * rescale,
+                              seq_shard_axis)
         return (o_glob / jnp.maximum(l_glob, 1e-30)).astype(qg.dtype)
 
     fn = jax.shard_map(
@@ -484,10 +507,11 @@ def decode_step_attention(params, x: jax.Array, cache_k: jax.Array,
         if "bk" in params:
             k_new = k_new + params["bk"].astype(dtype)
             v_new = v_new + params["bv"].astype(dtype)
-        pos = jnp.full((x.shape[0], 1), index, jnp.int32)
-        cos, sin = rotary_cos_sin(pos, cfg)
-        q = L.apply_rope(q, cos, sin)
-        k_new = L.apply_rope(k_new, cos, sin)
+        if cfg.position_embedding == "rope":
+            pos = jnp.full((x.shape[0], 1), index, jnp.int32)
+            cos, sin = rotary_cos_sin(pos, cfg)
+            q = L.apply_rope(q, cos, sin)
+            k_new = L.apply_rope(k_new, cos, sin)
         cache_k = jax.lax.dynamic_update_slice_in_dim(
             cache_k, k_new.astype(cache_k.dtype), index, axis=1)
         cache_v = jax.lax.dynamic_update_slice_in_dim(
@@ -495,6 +519,7 @@ def decode_step_attention(params, x: jax.Array, cache_k: jax.Array,
         eff_index = index
     else:
         eff_index = cache_k.shape[1] - 1  # attend over the whole encoder output
-    out = decode_attention(q, cache_k, cache_v, eff_index)
+    out = decode_attention(q, cache_k, cache_v, eff_index,
+                           scale=cfg.attention_multiplier or None)
     y = jnp.einsum("bshd,hdm->bsm", out, params["wo"].astype(dtype))
     return constrain(y, "batch", "seq", "embed"), cache_k, cache_v

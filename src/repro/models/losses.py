@@ -28,11 +28,16 @@ def _scan(*args, **kw):
     return jax.lax.scan(*args, **kw)
 
 def _ce_block(x: jax.Array, table: jax.Array, targets: jax.Array,
-              valid: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, C, D) · table: (V, D) · targets: (B, C) → (sum_nll, n_valid)."""
+              valid: jax.Array, logits_scaling: float = 1.0
+              ) -> Tuple[jax.Array, jax.Array]:
+    """x: (B, C, D) · table: (V, D) · targets: (B, C) → (sum_nll, n_valid).
+    The float32 logits are divided by ``logits_scaling`` (left alone at
+    1)."""
     logits = jnp.einsum("bcd,vd->bcv", x, table.astype(x.dtype))
     logits = constrain(logits, "batch", "seq", "vocab")
     logits = logits.astype(jnp.float32)
+    if logits_scaling != 1.0:
+        logits = logits / logits_scaling
     lse = jax.nn.logsumexp(logits, axis=-1)
     # gather-free target pick (iota-select fuses; take_along_axis is a
     # gather, which the SPMD partitioner mishandles in manual subgroups)
@@ -44,12 +49,14 @@ def _ce_block(x: jax.Array, table: jax.Array, targets: jax.Array,
 
 
 def ce_loss(x: jax.Array, table: jax.Array, targets: jax.Array,
-            mask: Optional[jax.Array] = None, chunk: int = 0) -> jax.Array:
+            mask: Optional[jax.Array] = None, chunk: int = 0,
+            logits_scaling: float = 1.0) -> jax.Array:
     """Mean next-token NLL. x: (B, S, D) final hidden · table: (V, D).
 
     ``mask`` (B, S) ∈ {0,1} selects positions contributing to the loss
     (e.g. text-only positions for the VLM). ``chunk`` > 0 scans the seq dim
-    in slices of that size (must divide S).
+    in slices of that size (must divide S). The logits are divided by
+    ``logits_scaling`` before the softmax, in each slice.
     """
     with scope("lm.loss"):
         b, s, d = x.shape
@@ -57,7 +64,8 @@ def ce_loss(x: jax.Array, table: jax.Array, targets: jax.Array,
                  else mask.astype(jnp.float32))
 
         if chunk <= 0 or s <= chunk or s % chunk != 0:
-            total, count = _ce_block(x, table, targets, valid)
+            total, count = _ce_block(x, table, targets, valid,
+                                     logits_scaling)
             return total / jnp.maximum(count, 1.0)
 
         nchunk = s // chunk
@@ -66,7 +74,7 @@ def ce_loss(x: jax.Array, table: jax.Array, targets: jax.Array,
         vs = valid.reshape(b, nchunk, chunk).swapaxes(0, 1)
 
         block = jax.checkpoint(
-            lambda xc, tc, vc: _ce_block(xc, table, tc, vc))
+            lambda xc, tc, vc: _ce_block(xc, table, tc, vc, logits_scaling))
 
         def body(carry, inp):
             tot, cnt = carry

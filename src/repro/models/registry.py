@@ -18,7 +18,7 @@ from repro.models import layers as L
 
 def _families():
     from repro.models.encdec import EncDecModel
-    from repro.models.hybrid import HybridModel
+    from repro.models.hybrid import HybridModel, InterleavedHybridModel
     from repro.models.ssm import SSMModel
     from repro.models.transformer import DecoderLM, PrefixVLM
 
@@ -28,6 +28,7 @@ def _families():
         "vlm": PrefixVLM,
         "ssm": SSMModel,
         "hybrid": HybridModel,
+        "interleaved": InterleavedHybridModel,
         "audio": EncDecModel,
     }
 

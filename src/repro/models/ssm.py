@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.base import ModelConfig
+from repro.core.telemetry import SSD, scope
 from repro.models import layers as L
 from repro.models.losses import ce_loss
 from repro.sharding import constrain
@@ -185,7 +186,15 @@ def _project(params, x):
 
 def mamba_fwd(params, x: jax.Array, cfg: ModelConfig,
               return_state: bool = False):
-    """x: (B, S, D) → (out, (ssm_state, conv tails) if return_state)."""
+    """x: (B, S, D) → (out, (ssm_state, conv tails) if return_state).
+
+    Traced under the ``lm.ssm`` scope, the SSD scan under ``lm.ssd``
+    inside it; each call counts once in :data:`SSD` by its path."""
+    with scope("lm.ssm"):
+        return _mamba_fwd(params, x, cfg, return_state)
+
+
+def _mamba_fwd(params, x, cfg: ModelConfig, return_state: bool):
     s = cfg.ssm
     b, l, d = x.shape
     d_inner = params["in_x"].shape[1]
@@ -204,7 +213,9 @@ def mamba_fwd(params, x: jax.Array, cfg: ModelConfig,
     xh = xi_conv.reshape(b, l, h, s.head_dim)
     xh = constrain(xh, "batch", "seq", "ssm_heads", "head_dim")
     a = -jnp.exp(params["a_log"].astype(jnp.float32))
-    y, state = ssd_chunked(xh, dt, a, bm_conv, cm_conv, s.chunk_size)
+    SSD.add("chunked")
+    with scope("lm.ssd"):
+        y, state = ssd_chunked(xh, dt, a, bm_conv, cm_conv, s.chunk_size)
     y = y + params["d_skip"].astype(y.dtype)[None, None, :, None] * xh
     y = y.reshape(b, l, d_inner)
 
@@ -332,7 +343,8 @@ class SSMModel:
             return fn(carry, lp), None
 
         if self.scan_layers:
-            x, ys = _scan(scan_body, x, params["layers"])
+            with SSD.repeated(cfg.n_layers):
+                x, ys = _scan(scan_body, x, params["layers"])
         else:
             ys_list = []
             for i in range(cfg.n_layers):

@@ -7,6 +7,7 @@ check (``_on_tpu``) is steered in the tests, never by an option of the
 program. The compiled kernel on a described chip is in
 ``tests/test_chip_compile.py``.
 """
+import dataclasses
 import types
 
 import jax
@@ -39,38 +40,40 @@ def _out_and_grads(fn, q, k, v, ct):
     return [np.asarray(x, np.float32) for x in (out, *vjp(ct))]
 
 
-def _flash(q, k, v):
-    return A._sdpa_flash(q, k, v, interpret=True)
+def _flash(q, k, v, scale=None):
+    return A._sdpa_flash(q, k, v, interpret=True, scale=scale)
 
 
-def _jnp(q, k, v):
+def _jnp(q, k, v, scale=None):
     return A._sdpa_jnp(q, k, v, A.make_mask(q.shape[1], k.shape[1],
-                                            "causal"))
+                                            "causal"), scale)
 
 
 def _max_err(got, want):
     return [float(np.max(np.abs(g - w))) for g, w in zip(got, want)]
 
 
-@pytest.mark.parametrize("heads,kv_heads", [(15, 5), (4, 4)],
-                         ids=["smollm-gqa", "mha"])
+@pytest.mark.parametrize("heads,kv_heads,scale",
+                         [(15, 5, None), (4, 4, None), (8, 2, 1 / 64)],
+                         ids=["smollm-gqa", "mha", "granite-scale"])
 @pytest.mark.parametrize("seq", [256, 512])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_flash_matches_jnp(heads, kv_heads, seq, dtype):
+def test_flash_matches_jnp(heads, kv_heads, scale, seq, dtype):
     """Output and the gradients of q, k and v (``jax.vjp``) against the
-    jnp path with ``make_mask``'s causal mask. float32: equal within a
+    jnp path with ``make_mask``'s causal mask, at the default softmax
+    scale 1/sqrt(hd) and at granite's 1/64. float32: equal within a
     tolerance fixed beforehand from float32 rounding over a 512-long
     row. bfloat16: each error against the float32 answer at most twice
     the jnp path's own bfloat16 error."""
     q, k, v, ct = _qkv(2, seq, heads, kv_heads, 64, jnp.dtype(dtype))
-    flash = _out_and_grads(_flash, q, k, v, ct)
-    ref = _out_and_grads(_jnp, q, k, v, ct)
+    flash = _out_and_grads(lambda *a: _flash(*a, scale=scale), q, k, v, ct)
+    ref = _out_and_grads(lambda *a: _jnp(*a, scale=scale), q, k, v, ct)
     if dtype == "float32":
         for got, want in zip(flash, ref):
             np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
         return
-    exact = _out_and_grads(_jnp, *[x.astype(jnp.float32)
-                                   for x in (q, k, v, ct)])
+    exact = _out_and_grads(lambda *a: _jnp(*a, scale=scale),
+                           *[x.astype(jnp.float32) for x in (q, k, v, ct)])
     own = _max_err(ref, exact)
     err = _max_err(flash, exact)
     assert all(e <= 2 * o for e, o in zip(err, own)), (err, own)
@@ -332,3 +335,82 @@ def test_flash_nests_under_the_local_sgd_replica_axis():
     axis is manual already, and the kernel's call is made manual over
     the rest; one sync block matches the jnp path's."""
     assert "OK" in run_with_devices(_LOCAL_SGD, n_devices=4)
+
+
+def _sdpa_before(q, k, v, mask):
+    """The jnp core as it read before the softmax scale became a setting:
+    scores divided by sqrt(hd)."""
+    b, s, h, hd = q.shape
+    kv = k.shape[2]
+    q = q.reshape(b, s, kv, h // kv, hd)
+    scores = jnp.einsum("bskgd,btkd->bkgst", q, k).astype(jnp.float32)
+    scores = scores / (hd ** 0.5)
+    scores = jnp.where(mask[None, None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bkgst,btkd->bskgd", probs, v).reshape(b, s, h, hd)
+
+
+@pytest.mark.parametrize("path", ["full", "flash"])
+def test_smollm_attention_unchanged_under_the_defaults(monkeypatch, path):
+    """smollm's config leaves the scale and RoPE at their defaults, and its
+    attention is bit for bit what it was: the jnp core divides the
+    scores by sqrt(hd) as before; the kernel's q is multiplied by
+    hd**-0.5 in q's dtype, as before."""
+    cfg = get_smoke("smollm-360m")
+    assert cfg.attention_multiplier == 0.0
+    assert cfg.position_embedding == "rope"
+    q, k, v, _ = _qkv(2, 512, 15, 5, 64, jnp.bfloat16)
+    if path == "full":
+        mask = A.make_mask(512, 512, "causal")
+        got = A._sdpa_jnp(q, k, v, mask)
+        want = _sdpa_before(q, k, v, mask)
+    else:
+        got = _flash(q, k, v)
+        want = _flash(q * jnp.asarray(64 ** -0.5, q.dtype), k, v, scale=1.0)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def _nope_reference(params, x, scale):
+    """Causal GQA attention with no positional embedding and the scores
+    times ``scale``, in float32 at full precision."""
+    hi = jax.lax.Precision.HIGHEST
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"], precision=hi)
+    k = jnp.einsum("bsd,dhk->bshk", x, params["wk"], precision=hi)
+    v = jnp.einsum("bsd,dhk->bshk", x, params["wv"], precision=hi)
+    b, s, h, hd = q.shape
+    kv = k.shape[2]
+    qg = q.reshape(b, s, kv, h // kv, hd)
+    scores = jnp.einsum("bskgd,btkd->bkgst", qg, k, precision=hi) * scale
+    causal = jnp.tril(jnp.ones((s, s), bool))
+    p = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+    o = jnp.einsum("bkgst,btkd->bskgd", p, v, precision=hi)
+    return jnp.einsum("bshd,hdm->bsm", o.reshape(b, s, h, hd), params["wo"],
+                      precision=hi)
+
+
+@pytest.mark.parametrize("path,seq,tpu", [("full", 64, False),
+                                          ("chunked", 2048, False),
+                                          ("flash", 512, True)])
+def test_nope_and_scale_on_every_path(monkeypatch, path, seq, tpu):
+    """granite's attention (no RoPE, scores times 1/64 in place of
+    1/sqrt(hd)) through ``full_attention`` on each of the three cores:
+    equal to a plain float32 NoPE attention within float32 rounding over
+    a row of ``seq`` (the kernel in interpret mode within 1e-4), and the
+    same whatever positions it is given."""
+    monkeypatch.setattr(A, "_on_tpu", lambda: tpu)
+    cfg = dataclasses.replace(get_smoke("granite-4.0-h-micro"), head_dim=64,
+                              attention_multiplier=1 / 64, dtype="float32")
+    assert cfg.position_embedding == "nope"
+    params = L.init_params(A.attn_defs(cfg), jax.random.key(1))
+    x = jax.random.normal(jax.random.key(2), (1, seq, cfg.d_model))
+    pos = jnp.broadcast_to(jnp.arange(seq)[None], (1, seq))
+    before = ATTENTION.counts()
+    got = A.full_attention(params, x, pos, cfg)
+    moved = A.full_attention(params, x, pos + 1000, cfg)
+    assert ATTENTION.since(before) == {**NONE, path: 2}
+    want = _nope_reference(params, x, 1 / 64)
+    tol = 1e-4 if path == "flash" else 1e-5
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+    np.testing.assert_array_equal(np.asarray(moved), np.asarray(got))

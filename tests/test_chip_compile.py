@@ -117,10 +117,11 @@ def test_flash_kernel_compiles(topo, no_compile_cache, compiled_kernels,
 
 
 def _lm_step_text(topo, chips: int, batch: int, seq: int = 4096,
-                  model_axis: int = 1) -> str:
+                  model_axis: int = 1, arch_cfg=None) -> str:
     """The smollm-360m DDP step (widths as published, 2 of its 32 layers,
-    AdamW, remat full) compiled for ``chips`` described chips, a data
-    axis of ``chips // model_axis`` holding the batch by a model axis."""
+    AdamW, remat full), or ``arch_cfg``'s, compiled for ``chips``
+    described chips, a data axis of ``chips // model_axis`` holding the
+    batch by a model axis."""
     import dataclasses
     import functools
     from repro.config import TrainConfig, get_arch
@@ -135,7 +136,8 @@ def _lm_step_text(topo, chips: int, batch: int, seq: int = 4096,
     mesh = Mesh(np.array(topo.devices[:chips]).reshape(shape),
                 ("data", "model"))
     cfg = TrainConfig(
-        model=dataclasses.replace(get_arch("smollm-360m"), n_layers=2),
+        model=arch_cfg or dataclasses.replace(get_arch("smollm-360m"),
+                                              n_layers=2),
         mesh=test_mesh_config(shape),
         data=DataConfig(seq_len=seq, global_batch=batch), remat="full")
     cfg = apply_overrides(cfg, ["optimizer.name=adamw"])
@@ -212,3 +214,31 @@ def test_lm_step_keeps_attention_split_over_a_model_axis(
     assert "tpu_custom_call" not in hlo
     assert re.search(r"f32\[4,5,3,256,4096\]", hlo)
     assert not re.search(r"f32\[[\d,]*512,4096\]", hlo)
+
+
+def test_hybrid_step_runs_the_flash_kernel_and_the_ssd_scan(
+        topo, no_compile_cache, compiled_kernels):
+    """granite-4.0-h-micro's widths (one Mamba-2 and one NoPE attention
+    layer of its published ones, the vocabulary's eighth) at S 8,192 on
+    one chip: the attention layer, scaled by 1/64, takes the splash
+    kernels, the Mamba-2 layer's SSD compiles as the chunked scan, and
+    the mixer's forward, remat and backward ops carry its scopes."""
+    import dataclasses
+    from repro.config import get_arch
+    from repro.core.telemetry import ATTENTION, SSD
+    cfg = dataclasses.replace(get_arch("granite-4.0-h-micro"), n_layers=2,
+                              layer_types=("mamba", "attention"),
+                              vocab_size=12_544)
+    attn0, ssd0 = ATTENTION.counts(), SSD.counts()
+    hlo = _lm_step_text(topo, chips=1, batch=1, seq=8192, arch_cfg=cfg)
+    assert ATTENTION.since(attn0) == {"flash": 1, "chunked": 0, "full": 0,
+                                      "pallas": 0}
+    assert SSD.since(ssd0) == {"chunked": 1}
+    kernels = re.findall(r"%(\S+) = .*custom_call_target=\"tpu_custom_call",
+                         hlo)
+    assert kernels and all(k.startswith("splash_mqa_") for k in kernels)
+    names = re.findall(r'op_name="([^"]*repro\.lm\.ss[md][^"]*)"', hlo)
+    assert any("/repro.lm.ssm/repro.lm.ssd/" in n for n in names)
+    assert any("transpose(" in n and "repro.lm.ssd" in n for n in names)
+    assert any("rematted_computation" in n and "repro.lm.ssm" in n
+               for n in names)
